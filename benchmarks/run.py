@@ -128,6 +128,8 @@ def write_step_summary(path: str, results: dict, baseline: dict,
 
 
 def main(argv=None) -> None:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="also write results as a name -> us_per_call JSON "

@@ -181,7 +181,7 @@ def _run_window(rings, bank, eps_params, xs, suffix, suffix_es, gid, *,
     mism = mism0 + suf_mism[gid]
     pre = (jnp.int32(d) - 2 * mism).astype(jnp.float32) + b1x[es]
     h = jnp.where(pre >= 0, 1.0, -1.0)
-    y = jnp.einsum("bh,bch->bc", h, w2x[es]) + b2x[es]
+    y = _refk.dense_pm1(h, w2x[es], b2x[es])
     verd = y[:, 0] > 0.0
     acts = _fusedk.actions_ref(y, ctrl)
 
@@ -193,7 +193,7 @@ def _run_window(rings, bank, eps_params, xs, suffix, suffix_es, gid, *,
         mism_e = _refk.popcount32(payload[:, None, :] ^ w1x[es]).sum(axis=-1)
         pre_e = (jnp.int32(d) - 2 * mism_e).astype(jnp.float32) + b1x[es]
         h_e = jnp.where(pre_e >= 0, 1.0, -1.0)
-        y_e = jnp.einsum("bh,bch->bc", h_e, w2x[es]) + b2x[es]
+        y_e = _refk.dense_pm1(h_e, w2x[es], b2x[es])
         wrong = (((y_e[:, 0] > 0.0) != verd) & pvalid).sum(dtype=jnp.int32)
 
     pv = pvalid.astype(jnp.int32)

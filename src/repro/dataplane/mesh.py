@@ -76,6 +76,7 @@ from repro.dataplane import rss
 from repro.dataplane import runtime as runtime_mod
 from repro.dataplane import telemetry as telemetry_mod
 from repro.dataplane.runtime import DataplaneRuntime
+from repro.kernels import ops
 
 
 class QuorumLost(NonFatalControlError):
@@ -610,6 +611,8 @@ class MeshDataplane:
                                if self._faults is not None else [])
         out["fanout"] = self.shards[0].fanout
         out["strategy"] = self.shards[0].strategy
+        out["engine"] = self.shards[0].engine
+        out["backend"] = ops._resolve(self.shards[0].backend)
         out["pipeline_depth"] = self.pipeline_depth
         out["policy"] = getattr(self.policy, "name", None)
         out["control"] = self.control.stats()

@@ -54,7 +54,6 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.control import (ControlPlane, FailQueues, ProgramReta,
@@ -65,6 +64,7 @@ from repro.dataplane import rss
 from repro.dataplane.ring import PacketRing
 from repro.dataplane.workloads.phases import SEQ_WORD
 from repro.dataplane.telemetry import Telemetry
+from repro.kernels import ops as _ops
 from repro.launch import mesh as mesh_lib
 
 _LOOP_STRATEGIES = ("fused", "grouped", "grouped_staged")
@@ -263,10 +263,16 @@ class DataplaneRuntime:
         self._mega = None
         if (self.megastep_ticks > 1 and fault_injector is None
                 and strategy == "fused"):
-            from repro.kernels import ops as _ops
             if _ops._resolve(backend) == "ref":
                 from repro.dataplane.megastep import MegastepEngine
                 self._mega = MegastepEngine(self)
+
+    @property
+    def engine(self) -> str:
+        """The execution engine that runs the ticks: ``megastep`` (one
+        compiled scan per window) or ``sequential`` (the per-tick loop,
+        also what ``megastep_ticks > 1`` falls back to when ineligible)."""
+        return "megastep" if self._mega is not None else "sequential"
 
     # -- worker construction ------------------------------------------------
 
@@ -284,9 +290,9 @@ class DataplaneRuntime:
         if fanout == "vmap":
             return jax.jit(per_queue)
         mesh, axis = queue_mesh(self.num_queues)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             per_queue, mesh=mesh,
-            in_specs=(P(), P(axis)), out_specs=P(axis), check_rep=False,
+            in_specs=(P(), P(axis)), out_specs=P(axis), check_vma=False,
         ))
 
     # -- control plane: command application (ControlPlane-only entry) -------
@@ -739,6 +745,8 @@ class DataplaneRuntime:
         out["conservation"] = self.audit_conservation()
         out["fanout"] = self.fanout
         out["strategy"] = self.strategy
+        out["engine"] = self.engine
+        out["backend"] = _ops._resolve(self.backend)
         out["pipeline_depth"] = self.pipeline_depth
         out["policy"] = getattr(self.policy, "name", None)
         out["control"] = self.control.stats()

@@ -20,7 +20,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -69,6 +68,6 @@ def compressed_psum(x, mesh: Mesh, axis: str):
         return (summed.astype(jnp.float32) * gscale).astype(xs.dtype)
 
     spec = P(*([None] * x.ndim))
-    return shard_map(
-        body, mesh=mesh, in_specs=(spec,), out_specs=spec, check_rep=False
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False
     )(x)

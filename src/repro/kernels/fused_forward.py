@@ -22,11 +22,11 @@ Two input modes:
   * **gather** (``row_ids`` given) — the batch stays in HBM in its original
     arrival order (``memory_space=ANY``); a prefetched per-row index table
     drives a DMA gather prologue that copies exactly the rows of each block
-    into VMEM scratch.  Grouped execution is therefore zero-copy: no
-    ``scatter_padded``/``gather_padded`` materialization of a padded batch
-    in HBM.  (Production note: the prologue issues one row DMA at a time;
-    a double-buffered start/wait-behind scheme can hide the latency further,
-    but even serialized the copies are HBM-sequential 1 KiB reads.)
+    into VMEM scratch, so no ``scatter_padded``/``gather_padded`` grouped
+    copy of the batch is made.  The TPU's DMA moves a row only as a whole
+    tile of a multiple of 128 lanes, so the rows are first zero-padded to
+    such a width (one XLA pad of the batch).  The prologue issues one row
+    DMA at a time; a start-all, wait-all scheme could hide the latency.
 
 ``meta_words > 0`` means ``x`` rows are full packets (reg0 metadata followed
 by payload words); the parse is then inline too — the kernel slices the
@@ -55,7 +55,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-PACK = 32
+from .bnn_xnor import PACK, xnor_mismatches
+from .ref import dense_pm1
 
 # reg0 layout + Pi codes, mirrored from repro.core.packet.
 CTRL_WORD = 2
@@ -65,9 +66,8 @@ ACTION_DROP = 1
 ACTION_FLAG = 2
 
 
-def actions_ref(scores: jnp.ndarray, ctrl_words: jnp.ndarray) -> jnp.ndarray:
-    """Pi oracle on (B, C) scores + (B,) uint32 control words -> (B,) i32."""
-    malicious = scores[:, 0] > 0.0
+def _pi(malicious: jnp.ndarray, ctrl_words: jnp.ndarray) -> jnp.ndarray:
+    """Pi, elementwise: verdicts + uint32 control words -> i32 actions."""
     monitor = (ctrl_words & jnp.uint32(CTRL_MONITOR_ONLY)) != 0
     return jnp.where(
         malicious,
@@ -76,33 +76,32 @@ def actions_ref(scores: jnp.ndarray, ctrl_words: jnp.ndarray) -> jnp.ndarray:
     ).astype(jnp.int32)
 
 
+def actions_ref(scores: jnp.ndarray, ctrl_words: jnp.ndarray) -> jnp.ndarray:
+    """Pi oracle on (B, C) scores + (B,) uint32 control words -> (B,) i32."""
+    return _pi(scores[:, 0] > 0.0, ctrl_words)
+
+
 def _bnn_block(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, *, meta_words, chunk,
                d_bits):
     """Full executor on one block: x_ref rows (meta + payload words) ->
-    (block_b, C) f32 scores, entirely in VMEM."""
-    w_words = d_bits // PACK
-    n_chunks = w_words // chunk
-    n_hidden = w1_ref.shape[1]
-    bb = x_ref.shape[0]
+    (block_b, C) f32 scores, entirely in VMEM.
 
-    def body(c, acc):
-        xs = x_ref[:, pl.ds(meta_words + c * chunk, chunk)]
-        ws = w1_ref[0, :, pl.ds(c * chunk, chunk)]  # selected slot only
-        xor = jnp.bitwise_xor(xs[:, None, :], ws[None, :, :])
-        return acc + jax.lax.population_count(xor).astype(jnp.int32).sum(axis=-1)
-
-    mism = jax.lax.fori_loop(0, n_chunks, body, jnp.zeros((bb, n_hidden), jnp.int32))
-    pre = (jnp.int32(d_bits) - 2 * mism).astype(jnp.float32) + b1_ref[0][None, :]
+    Layer 1 is integer popcount arithmetic, bit-exact against the oracle;
+    layer 2 is the oracle's own ``dense_pm1`` (elementwise products and a
+    lane sum per column; the chip compiler refuses a (block_b, H) x (H, C)
+    dot followed by the bias add with "Lane broadcast")."""
+    mism = xnor_mismatches(x_ref, w1_ref.at[0], x_off=meta_words,
+                           w_words=d_bits // PACK, chunk=chunk)
+    pre = (jnp.int32(d_bits) - 2 * mism).astype(jnp.float32) + b1_ref[0]
     h = jnp.where(pre >= 0, 1.0, -1.0)
-    y = jnp.dot(h, w2_ref[0].T, preferred_element_type=jnp.float32)
-    return y + b2_ref[0][None, :]
+    return dense_pm1(h, w2_ref[...], b2_ref[0])
 
 
 def _emit(x_ref, y, out_refs, with_actions):
     out_refs[0][...] = y
     if with_actions:
-        ctrl = x_ref[:, CTRL_WORD]
-        out_refs[1][...] = actions_ref(y, ctrl)[:, None]
+        ctrl = x_ref[:, CTRL_WORD:CTRL_WORD + 1]            # (block_b, 1)
+        out_refs[1][...] = _pi(y[:, :1] > 0.0, ctrl)
 
 
 def _fused_contig_kernel(slots_ref, x_ref, w1_ref, b1_ref, w2_ref, b2_ref,
@@ -117,20 +116,19 @@ def _fused_gather_kernel(slots_ref, rows_ref, x_hbm, w1_ref, b1_ref, w2_ref,
                          b2_ref, *out_refs_and_scratch, meta_words, chunk,
                          d_bits, with_actions):
     del slots_ref
-    *out_refs, x_vmem, sem = out_refs_and_scratch
+    *out_refs, x_rows, x_vmem, sem = out_refs_and_scratch
     i = pl.program_id(0)
     bb = out_refs[0].shape[0]
 
     def copy_row(r, carry):
         src = rows_ref[i * bb + r]
-        cp = pltpu.make_async_copy(
-            x_hbm.at[pl.ds(src, 1)], x_vmem.at[pl.ds(r, 1)], sem
-        )
+        cp = pltpu.make_async_copy(x_hbm.at[src], x_rows.at[r], sem)
         cp.start()
         cp.wait()
         return carry
 
     jax.lax.fori_loop(0, bb, copy_row, 0)
+    x_vmem[...] = x_rows[:, 0, :]
     y = _bnn_block(x_vmem, w1_ref, b1_ref, w2_ref, b2_ref,
                    meta_words=meta_words, chunk=chunk, d_bits=d_bits)
     _emit(x_vmem, y, out_refs, with_actions)
@@ -151,7 +149,7 @@ def fused_forward(
     row_ids: jnp.ndarray | None = None,  # (n_blocks * block_b,) i32 gather map
     *,
     block_b: int = 256,
-    chunk: int = 64,
+    chunk: int = 128,
     interpret: bool = False,
     meta_words: int = 0,
     with_actions: bool = False,
@@ -189,11 +187,15 @@ def fused_forward(
         out_shape.append(jax.ShapeDtypeStruct((n_rows, 1), jnp.int32))
         out_specs.append(pl.BlockSpec((block_b, 1), lambda i, *_: (i, 0)))
 
+    # Biases ride as (K, 1, n) so each slot's block is the whole trailing
+    # (1, n) tile: the TPU lowering refuses a (1, n) block cut from (K, n).
+    bank_b1 = bank_b1.reshape(k, 1, h)
+    bank_b2 = bank_b2.reshape(k, 1, c)
     bank_specs = [
         pl.BlockSpec((1, h, w_words), lambda i, s, *_: (s[i], 0, 0)),
-        pl.BlockSpec((1, h), lambda i, s, *_: (s[i], 0)),
+        pl.BlockSpec((1, 1, h), lambda i, s, *_: (s[i], 0, 0)),
         pl.BlockSpec((1, c, h), lambda i, s, *_: (s[i], 0, 0)),
-        pl.BlockSpec((1, c), lambda i, s, *_: (s[i], 0)),
+        pl.BlockSpec((1, 1, c), lambda i, s, *_: (s[i], 0, 0)),
     ]
 
     if row_ids is None:
@@ -212,18 +214,25 @@ def fused_forward(
     else:
         if row_ids.shape != (n_rows,):
             raise ValueError(f"row_ids must be ({n_rows},), got {row_ids.shape}")
+        # The chip's DMA moves one row only as a whole (1, lanes) tile
+        # whose lane count is a multiple of 128: view the rows as
+        # (B, 1, lanes), zero-padded past ``total_words``.
+        lanes = -(-total_words // 128) * 128
+        x_tiles = jnp.pad(x, ((0, 0), (0, lanes - total_words))).reshape(
+            x.shape[0], 1, lanes)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n_blocks,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] + bank_specs,
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] + bank_specs,
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((block_b, total_words), jnp.uint32),
+                pltpu.VMEM((block_b, 1, lanes), jnp.uint32),
+                pltpu.VMEM((block_b, lanes), jnp.uint32),
                 pltpu.SemaphoreType.DMA,
             ],
         )
         kernel = functools.partial(_fused_gather_kernel, **kern_kwargs)
-        operands = (block_slots, row_ids.astype(jnp.int32), x,
+        operands = (block_slots, row_ids.astype(jnp.int32), x_tiles,
                     bank_w1, bank_b1, bank_w2, bank_b2)
 
     out = pl.pallas_call(

@@ -26,6 +26,12 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run in interpret mode: only off the TPU
+    (tests on the CPU).  On the TPU they are always compiled by Mosaic."""
+    return not _on_tpu()
+
+
 def _resolve(backend: str) -> str:
     if backend == "auto":
         return "pallas" if _on_tpu() else "ref"
@@ -45,7 +51,7 @@ def xnor_matmul(x_packed, w_packed, *, backend: str = "auto"):
     if backend == "mxu":
         return _ref.xnor_matmul_mxu_ref(x_packed, w_packed)
     return _bnn_xnor.xnor_matmul(
-        x_packed, w_packed, interpret=not _on_tpu()
+        x_packed, w_packed, interpret=interpret_mode()
     )
 
 
@@ -55,7 +61,7 @@ def bnn_forward(params, x_packed, *, backend: str = "auto"):
     pre = xnor_matmul(x_packed, params["w1p"], backend=backend).astype(jnp.float32)
     pre = pre + params["b1"][None, :]
     h = jnp.where(pre >= 0, 1.0, -1.0)
-    return h @ params["w2"].T + params["b2"][None, :]
+    return _ref.dense_pm1(h, params["w2"], params["b2"][None, :])
 
 
 # ---------------------------------------------------------------------------
@@ -82,8 +88,7 @@ def bnn_forward_banked(bank, x_packed, slots, *, backend: str = "auto"):
         )
         pre = pre + bank["b1"][slots]
         h = jnp.where(pre >= 0, 1.0, -1.0)
-        y = jnp.einsum("bh,bch->bc", h, bank["w2"][slots]) + bank["b2"][slots]
-        return y
+        return _ref.dense_pm1(h, bank["w2"][slots], bank["b2"][slots])
     return _ref.banked_xnor_forward_ref(
         bank["w1p"], bank["b1"], bank["w2"], bank["b2"], x_packed, slots
     )
@@ -130,7 +135,7 @@ def bnn_forward_fused(
         )
     return _fused.fused_forward(
         x_packed, bank["w1p"], bank["b1"], bank["w2"], bank["b2"],
-        block_slots, row_ids, block_b=block_b, interpret=not _on_tpu(),
+        block_slots, row_ids, block_b=block_b, interpret=interpret_mode(),
     )
 
 
@@ -165,7 +170,7 @@ def packet_forward_fused(
     scores, actions = fwd(
         packets, bank["w1p"], bank["b1"], bank["w2"], bank["b2"],
         block_slots, row_ids, block_b=block_b, meta_words=meta_words,
-        with_actions=True, interpret=not _on_tpu(),
+        with_actions=True, interpret=interpret_mode(),
     )
     return scores, actions[:, 0]
 
@@ -180,5 +185,5 @@ def banked_matmul(x, w, b, block_slots, *, block_b: int = 128, backend: str = "a
         slots = _ref.expand_block_slots(block_slots, bb, bsz)
         return _ref.banked_matmul_ref(x, w, b, slots)
     return _banked.banked_matmul(
-        x, w, b, block_slots, block_b=bb, interpret=not _on_tpu()
+        x, w, b, block_slots, block_b=bb, interpret=interpret_mode()
     )

@@ -86,6 +86,42 @@ def xnor_matmul_ref(x_packed: jnp.ndarray, w_packed: jnp.ndarray) -> jnp.ndarray
     return jnp.int32(d) - 2 * mism
 
 
+def tree_sum(v: jnp.ndarray) -> jnp.ndarray:
+    """Sum over the last axis in one fixed order, keeping it as size 1.
+
+    The lanes are folded in halves (``v[:n] + v[n:2n]``) after zero-padding
+    to a power of two (adding 0.0 is exact).  Every backend then performs
+    the same f32 additions in the same order, so the result is
+    bit-identical on XLA:CPU, XLA:TPU and inside a Pallas kernel, where a
+    plain ``jnp.sum`` is free to pick its own order."""
+    n = v.shape[-1]
+    width = 1 << (n - 1).bit_length()
+    if width != n:
+        v = jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, width - n)])
+    while width > 1:
+        width //= 2
+        v = v[..., :width] + v[..., width:2 * width]
+    return v
+
+
+def dense_pm1(h: jnp.ndarray, w2: jnp.ndarray, b2: jnp.ndarray) -> jnp.ndarray:
+    """Layer 2 on +-1 activations: ``y[:, c] = sum_h h * w2[c] + b2[c]``.
+
+    h: (B, H) in {+1, -1}; w2: (C, H) shared, or (B, C, H) / (1, C, H);
+    b2 broadcastable to (B, C).  Elementwise products and a ``tree_sum``
+    per output column, never a matmul: each product is exact and the
+    additions run in one fixed order, so scores agree bit for bit across
+    backends (an f32 matmul at default precision on the TPU would drop to
+    bf16 passes).  The fused kernel calls this very function on its block,
+    in a form the chip compiler accepts (a 3-D product summed over its last
+    axis is refused there).
+    """
+    w2 = w2.reshape((-1,) + w2.shape[-2:])
+    cols = [tree_sum(h * w2[:, j, :]) for j in range(w2.shape[1])]
+    y = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=-1)
+    return y + b2
+
+
 def bnn_forward_ref(
     w1_packed: jnp.ndarray,  # (H, W) uint32
     b1: jnp.ndarray,         # (H,) float32
@@ -96,7 +132,7 @@ def bnn_forward_ref(
     """h = sign(W1 x + b1); y = W2 h + b2   (paper Eq. 1).  -> (B, C) f32."""
     pre = xnor_matmul_ref(x_packed, w1_packed).astype(jnp.float32) + b1[None, :]
     h = jnp.where(pre >= 0, 1.0, -1.0)
-    return h @ w2.T + b2[None, :]
+    return dense_pm1(h, w2, b2[None, :])
 
 
 def banked_matmul_ref(
@@ -128,8 +164,7 @@ def banked_xnor_forward_ref(
     mism = popcount32(xor).sum(axis=-1)
     pre = (jnp.int32(d) - 2 * mism).astype(jnp.float32) + bank_b1[slots]
     h = jnp.where(pre >= 0, 1.0, -1.0)                # (B, H)
-    y = jnp.einsum("bh,bch->bc", h, bank_w2[slots]) + bank_b2[slots]
-    return y
+    return dense_pm1(h, bank_w2[slots], bank_b2[slots])
 
 
 # ---------------------------------------------------------------------------

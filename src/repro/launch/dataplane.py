@@ -73,6 +73,8 @@ from repro.control import make_policy
 from repro.core import executor
 from repro.dataplane import (DataplaneRuntime, MeshDataplane, faults,
                              workloads)
+from repro.kernels import ops
+from repro.launch.cache import enable_compile_cache
 
 
 def _print_run_report(rt, reports, hosts: int, queues_per_host: int) -> dict:
@@ -208,7 +210,7 @@ def _finish_observer(srv, rt, args) -> None:
         srv.stop()
 
 
-def _replay_main(args) -> None:
+def _replay_main(args) -> dict:
     """``--trace replay PATH``: runtime shape comes from the trace."""
     trace = workloads.load(args.trace[1])
     meta = trace.meta
@@ -243,6 +245,7 @@ def _replay_main(args) -> None:
     if (not rep["ok"] or rep["digest_ok"] is False or not aud["ok"]
             or aud["wrong_verdict"] or not snap["continuity"]["ok"]):
         sys.exit(1)
+    return snap
 
 
 class CacheChurnDriver:
@@ -285,7 +288,9 @@ class CacheChurnDriver:
 
 def _make_slot_cache(rt, args, bank):
     """``--slot-cache N``: register N models (the bank's own slots first,
-    then fresh inits) and return (cache, churn schedule, prefetcher)."""
+    then fresh inits) and return (cache, churn schedule, prefetcher).  The
+    schedule starts at the first model that is not resident, so even a
+    short run misses, evicts and stages."""
     from repro.control import SlotCache, SlotMixPrefetcher
     from repro.core import bank as bank_lib
     n = args.slot_cache
@@ -299,7 +304,7 @@ def _make_slot_cache(rt, args, bank):
             cache.register(name, executor.init_params(
                 jax.random.PRNGKey(args.seed + 1000 + i)))
     prefetcher = SlotMixPrefetcher(cache) if args.prefetch else None
-    return cache, names, prefetcher
+    return cache, names[k:] + names[:k], prefetcher
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -404,7 +409,8 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> dict:
+    enable_compile_cache()
     ap = build_parser()
     args = ap.parse_args(argv)
     if args.hosts < 1:
@@ -417,8 +423,7 @@ def main(argv=None) -> None:
         ap.error("--slot-cache must be >= 1")
 
     if args.trace and args.trace[0] == "replay":
-        _replay_main(args)
-        return
+        return _replay_main(args)
 
     deploy_active = bool(args.auto_remediate or args.deploy_demo)
     if deploy_active and args.slots < 2:
@@ -478,6 +483,12 @@ def main(argv=None) -> None:
           f"strategy={args.strategy}, "
           f"ring={args.ring_capacity}, depth={rt.pipeline_depth}, "
           f"policy={getattr(policy, 'name', None)}")
+    print(f"engine: {rt.engine}, backend={ops._resolve('auto')} on "
+          f"{jax.default_backend()}")
+    if args.megastep_ticks > 1 and rt.engine != "megastep":
+        print(f"engine: --megastep-ticks {args.megastep_ticks} not armed "
+              f"(the megastep runs the fused strategy on the ref backend "
+              f"without a fault plan); ticks run sequentially")
 
     stream, detector = _make_detector(rt, args, num_slots=args.slots)
     observer = _start_observer(rt, args, num_slots=args.slots,
@@ -573,6 +584,7 @@ def main(argv=None) -> None:
     if (not ok or not aud["ok"] or aud["wrong_verdict"]
             or not snap["continuity"]["ok"]):
         sys.exit(1)
+    return snap
 
 
 if __name__ == "__main__":

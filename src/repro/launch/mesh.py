@@ -13,13 +13,18 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def _build(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """The one funnel every mesh layout goes through."""
+    """The one funnel every mesh layout goes through.  Axes are ``Auto``:
+    the compiler propagates shardings (``jax.make_mesh`` now defaults to
+    ``Explicit`` axes, under which plain indexing of a sharded result,
+    such as one queue's slice of the fan-out output, is an error)."""
     if len(shape) != len(axes):
         raise ValueError(f"mesh shape {shape} does not match axes {axes}")
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -23,10 +23,12 @@ from repro.core import bank as bank_lib
 from repro.core import packet as pkt
 from repro.core import pipeline, switching
 from repro.data import packets as pk
+from repro.launch.cache import enable_compile_cache
 from repro.train import bnn
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--packets", type=int, default=8192)
     ap.add_argument("--epochs", type=int, default=3)

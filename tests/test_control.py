@@ -249,7 +249,7 @@ _OP = st.sampled_from(
 
 
 @settings(max_examples=12, deadline=None)
-@given(st.lists(_OP, min_size=4, max_size=24), st.integers(0, 2**31))
+@given(ops=st.lists(_OP, min_size=4, max_size=24), seed=st.integers(0, 2**31))
 def test_epoch_interleaving_invariants(ops, seed, bank2, spare_params):
     """Any interleaving of valid command epochs with traffic keeps the
     ring conservation invariants and per-queue FIFO ordering;

@@ -70,7 +70,8 @@ def test_legalize_drops_indivisible():
 def test_compressed_psum_matches_exact():
     out = _subproc("""
         from repro.distributed.compress import compressed_psum
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 16))
         exact = x * 4  # psum over data of replicated x = 4x
         got = compressed_psum(x, mesh, "data")
@@ -94,7 +95,8 @@ def test_sharded_train_step_multidevice():
             d_ff=128, vocab_size=256, vocab_pad_multiple=32,
             dtype="float32", remat="none")
         opt_cfg = opt_lib.OptimizerConfig(warmup_steps=0, total_steps=5)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rules = sh.ShardingRules(tp_axis="model", fsdp_axis=None,
                                  dp_axes=("data",))
         state = ts_lib.init_train_state(jax.random.PRNGKey(0), cfg, opt_cfg)

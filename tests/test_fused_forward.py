@@ -81,7 +81,9 @@ def test_fused_contiguous_mode_matches_grouped_kernel():
 
 def test_packet_forward_fused_inline_actions():
     """The megakernel's in-kernel parse + Pi matches the staged pipeline,
-    including the monitor-only control bit."""
+    including the monitor-only control bit, at the paper's H32 width.
+    Scores compare exactly too: every backend runs layer 2 as the same
+    fixed-order sum (``ref.dense_pm1``)."""
     num_slots, b = 4, 48
     bank = executor.init_bank(jax.random.PRNGKey(0), num_slots)
     rng = np.random.default_rng(0)

@@ -109,3 +109,19 @@ def test_ops_backends_agree(rng):
     y_pal = ops.bnn_forward(params, x, backend="pallas")
     np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_mxu), atol=1e-3)
     np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_pal), atol=1e-5)
+
+
+@pytest.mark.parametrize("width", [1, 5, 16, 32])
+def test_tree_sum_fixed_order(rng, width):
+    """``tree_sum`` folds halves of a zero-padded power-of-two width, the
+    one order every backend reproduces (so scores agree bit for bit)."""
+    v = rng.normal(size=(6, width)).astype(np.float32)
+    want = v.copy()
+    n = 1 << (width - 1).bit_length()
+    want = np.pad(want, ((0, 0), (0, n - width)))
+    while n > 1:
+        n //= 2
+        want = (want[:, :n] + want[:, n:2 * n]).astype(np.float32)
+    got = np.asarray(ref.tree_sum(jnp.asarray(v)))
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(got[:, 0], v.sum(axis=1), rtol=1e-5, atol=1e-5)

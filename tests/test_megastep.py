@@ -185,3 +185,19 @@ def test_megastep_batched_retires_respect_sampler_and_stream_bounds(bank2):
     s = stream.snapshot_stats()
     assert s["next_sid"] == s["buffered"] + s["dropped_events"]
     assert s["dropped_events"] > 0  # the tiny ring really overflowed
+
+
+@pytest.mark.parametrize("backend,megastep_ticks,engine", [
+    ("ref", 8, "megastep"),
+    ("ref", 1, "sequential"),
+    ("pallas", 8, "sequential"),   # the TPU's auto backend: no megastep
+])
+def test_snapshot_reports_engine(bank2, backend, megastep_ticks, engine):
+    """The run report names the engine that ran, so a megastep request
+    that falls back to the per-tick loop is visible."""
+    rt = DataplaneRuntime(bank2, num_queues=NUM_QUEUES, strategy="fused",
+                          batch=BATCH, backend=backend,
+                          megastep_ticks=megastep_ticks)
+    snap = rt.snapshot()
+    assert rt.engine == snap["engine"] == engine
+    assert snap["backend"] == backend

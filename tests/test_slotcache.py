@@ -301,7 +301,7 @@ def _drive(ops, seed, bank4, params_pool, double_buffer):
 
 
 @settings(max_examples=8, deadline=None)
-@given(st.lists(_OP, min_size=4, max_size=20), st.integers(0, 2**31))
+@given(ops=st.lists(_OP, min_size=4, max_size=20), seed=st.integers(0, 2**31))
 def test_cache_interleaving_flip_equals_restage(ops, seed, bank4,
                                                 params_pool):
     """Any interleaving of traffic with cache hits, misses, evictions,
