@@ -138,6 +138,13 @@ def _exclusive_cumsum(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([jnp.zeros(1, x.dtype), jnp.cumsum(x)[:-1]])
 
 
+def padded_rows(b: int, num_slots: int, block_b: int) -> int:
+    """``PaddedGrouping.b_pad``: the static row count a batch of ``b``
+    rows over ``num_slots`` slots is padded to, in whole ``block_b``
+    blocks — the rows the grouped kernel's grid covers."""
+    return ((b + num_slots * block_b + block_b - 1) // block_b) * block_b
+
+
 def group_by_slot_padded(
     slots: jnp.ndarray, num_slots: int, block_b: int
 ) -> PaddedGrouping:
@@ -148,7 +155,7 @@ def group_by_slot_padded(
     padded = ((counts + block_b - 1) // block_b) * block_b
     rank = jnp.arange(b) - _exclusive_cumsum(counts)[sorted_slots]
     dest = (_exclusive_cumsum(padded)[sorted_slots] + rank).astype(jnp.int32)
-    b_pad = ((b + num_slots * block_b + block_b - 1) // block_b) * block_b
+    b_pad = padded_rows(b, num_slots, block_b)
     seg_end = jnp.cumsum(padded)
     block_starts = jnp.arange(b_pad // block_b) * block_b
     block_seg = jnp.searchsorted(seg_end, block_starts, side="right")

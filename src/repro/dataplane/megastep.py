@@ -50,8 +50,8 @@ asserts the two agree on per-queue pop counts.
 Bit-exactness contract (the hypothesis property in
 ``tests/test_megastep.py``): verdicts, slots, actions, telemetry count
 totals, and epoch apply ticks are identical to N sequential ``tick()``
-calls.  Wall-clock attribution (``busy_s``, latency histograms, epoch
-``apply_latency_us``) is measured at flush granularity instead and is
+calls.  Wall-clock readings (latency histograms, epoch
+``apply_latency_us``) are taken at flush granularity instead and are
 outside the contract.
 """
 
@@ -255,8 +255,6 @@ class MegastepEngine:
         self._seq = 0
         self._window_bank = None         # bank version at window start
         self._window_pin = None          # donation pin on that buffer
-        self._window_t0: float | None = None
-        self._last_flush_s: float | None = None
 
     # -- staging (the runtime's dispatch/tick edge) --------------------------
 
@@ -343,7 +341,6 @@ class MegastepEngine:
             # the staging shadow, and staging donates unpinned buffers —
             # the window must keep computing against its opening version
             self._window_pin = self.rt.bank_pin()
-            self._window_t0 = time.perf_counter()
 
     def _sync_reta(self) -> None:
         """Refresh the decorative device RETA mirror iff the host table
@@ -361,7 +358,12 @@ class MegastepEngine:
     # -- flush ---------------------------------------------------------------
 
     def flush(self) -> None:
-        """Run the staged window on device and drain everything host-side."""
+        """Run the staged window on device and drain everything host-side
+        (one ``dp.flush`` span of the runtime's ``HostSpans``)."""
+        with self.rt.spans.span("dp.flush"):
+            self._flush()
+
+    def _flush(self) -> None:
         rt = self.rt
         steps, self._steps = self._steps, []
         deltas = [d for _, d in self._deltas]
@@ -493,7 +495,6 @@ class MegastepEngine:
         self.rt.bank_unpin(self._window_pin)
         self._window_pin = None
         self._window_bank = None
-        self._window_t0 = None
         self._sync_reta()
 
     def _flush_trailing(self) -> None:
@@ -526,11 +527,6 @@ class MegastepEngine:
                 f"device ring divergence: device popped {completed.tolist()}"
                 f" rows/queue, host mirror {host.tolist()}")
         now = time.perf_counter()
-        start = (self._window_t0 if self._last_flush_s is None
-                 else max(self._window_t0, self._last_flush_s))
-        span = now - start
-        self._last_flush_s = now
-        total = int(completed.sum())
         for q in range(rt.num_queues):
             if not completed[q]:
                 continue
@@ -542,8 +538,7 @@ class MegastepEngine:
                 per_slot_total=ctr["per_slot"][q],
                 per_slot_malicious=ctr["per_slot_mal"][q],
                 actions=ctr["actions"][q],
-                latency_us=(now - lat) * 1e6,
-                busy_s=span * int(completed[q]) / total)
+                latency_us=(now - lat) * 1e6)
             rt.rings[q].mark_completed(int(completed[q]))
         if rt.audit:
             rt.telemetry.wrong_verdict += int(ctr["wrong"])

@@ -43,6 +43,13 @@ every tick captures the bank/RETA version current at its dispatch.
 ``audit=True`` re-scores every tick through the exact ``take`` path
 *against that captured bank* and counts verdict mismatches — valid
 across every control command kind, not just slot swaps.
+
+``self.spans`` (`repro.obs.spans.HostSpans`, off until enabled) names
+each host step of the sequential engine: ``dp.dispatch`` (``.hash``,
+``.push``), ``dp.tick`` (``.control``, ``.pop``, then per queue ``.pad``,
+``.h2d``, ``.launch``) and the retire (``dp.retire.wait``, ``.d2h``,
+``.tap``, ``.telemetry``, ``.audit``), with the counters
+``dp.rows_popped``, ``dp.ring_wait_ns`` and ``dp.kernel_rows``.
 """
 
 from __future__ import annotations
@@ -66,6 +73,7 @@ from repro.dataplane.workloads.phases import SEQ_WORD
 from repro.dataplane.telemetry import Telemetry
 from repro.kernels import ops as _ops
 from repro.launch import mesh as mesh_lib
+from repro.obs.spans import HostSpans
 
 _LOOP_STRATEGIES = ("fused", "grouped", "grouped_staged")
 
@@ -146,15 +154,14 @@ def drain_rings(rt, max_ticks: int = 100_000) -> int:
 class _InFlight:
     """One dispatched-but-unretired tick (the device stage of the pipeline)."""
 
-    __slots__ = ("tick", "popped", "counts", "results", "bank", "t0")
+    __slots__ = ("tick", "popped", "counts", "results", "bank")
 
-    def __init__(self, tick, popped, counts, results, bank, t0):
+    def __init__(self, tick, popped, counts, results, bank):
         self.tick = tick
         self.popped = popped      # [(rows, ts)] per queue
         self.counts = counts      # rows popped per queue
         self.results = results    # {queue: PacketResult} (async)
         self.bank = bank          # bank version captured at dispatch
-        self.t0 = t0
 
 
 class DataplaneRuntime:
@@ -235,7 +242,7 @@ class DataplaneRuntime:
             raise ValueError("pipeline_depth must be >= 1")
         self.pipeline_depth = int(pipeline_depth)
         self._inflight: collections.deque[_InFlight] = collections.deque()
-        self._last_retire_s: float | None = None
+        self.spans = HostSpans()
         self._tick_count = 0
         self._faults = fault_injector
         self.control = ControlPlane(self, log_capacity=log_capacity,
@@ -249,6 +256,11 @@ class DataplaneRuntime:
             raise ValueError(f"unknown fanout {fanout!r}")
         self.fanout = fanout
         self._vstep = None if fanout == "loop" else self._build_fanout(fanout)
+        # rows one launch computes per queue: the slot-grouped strategies
+        # pad every slot's segment to whole blocks
+        self._kernel_rows = (
+            bank_lib.padded_rows(self.batch, self.num_slots, self.block_b)
+            if strategy in _LOOP_STRATEGIES else self.batch)
         if megastep_ticks < 1:
             raise ValueError("megastep_ticks must be >= 1")
         self.megastep_ticks = int(megastep_ticks)
@@ -546,6 +558,11 @@ class DataplaneRuntime:
         twice.  The caller then owns per-bucket load accounting; when
         omitted the runtime hashes and resolves through its own RETA.
         """
+        with self.spans.span("dp.dispatch"):
+            return self._dispatch(packets_np, now, queues)
+
+    def _dispatch(self, packets_np, now, queues) -> dict:
+        sp = self.spans
         self._apply_control()
         if self._t_start is None:
             self._t_start = time.perf_counter()
@@ -553,10 +570,13 @@ class DataplaneRuntime:
             now = time.perf_counter()
         packets_np = np.asarray(packets_np)
         if queues is None:
-            h = rss.toeplitz_hash(rss.flow_words_of(packets_np), self.rss_key)
-            bucket = rss.bucket_index(h, len(self.reta)).astype(np.int64)
-            self.bucket_load += np.bincount(bucket, minlength=len(self.reta))
-            q = self.reta[bucket]
+            with sp.span("dp.dispatch.hash"):
+                h = rss.toeplitz_hash(rss.flow_words_of(packets_np),
+                                      self.rss_key)
+                bucket = rss.bucket_index(h, len(self.reta)).astype(np.int64)
+                self.bucket_load += np.bincount(bucket,
+                                                minlength=len(self.reta))
+                q = self.reta[bucket]
         else:
             q = np.asarray(queues, np.int64)
             if q.size and not (0 <= q.min() and q.max() < self.num_queues):
@@ -567,18 +587,19 @@ class DataplaneRuntime:
                     f"{self.num_queues} queues")
         self.telemetry.touch(now)
         per_queue = []
-        for i, ring in enumerate(self.rings):
-            rows = packets_np[q == i]
-            admitted = ring.push(rows, now)
-            if self._record and admitted < rows.shape[0]:
-                self.dropped_seq.extend(
-                    int(s) for s in rows[admitted:, SEQ_WORD])
-            if self.on_drop is not None and admitted < rows.shape[0]:
-                self.on_drop(i, rows[admitted:])
-            self.telemetry.record_drops(i, int(rows.shape[0]) - admitted)
-            per_queue.append({"offered": int(rows.shape[0]),
-                              "admitted": admitted,
-                              "dropped": int(rows.shape[0]) - admitted})
+        with sp.span("dp.dispatch.push"):
+            for i, ring in enumerate(self.rings):
+                rows = packets_np[q == i]
+                admitted = ring.push(rows, now)
+                if self._record and admitted < rows.shape[0]:
+                    self.dropped_seq.extend(
+                        int(s) for s in rows[admitted:, SEQ_WORD])
+                if self.on_drop is not None and admitted < rows.shape[0]:
+                    self.on_drop(i, rows[admitted:])
+                self.telemetry.record_drops(i, int(rows.shape[0]) - admitted)
+                per_queue.append({"offered": int(rows.shape[0]),
+                                  "admitted": admitted,
+                                  "dropped": int(rows.shape[0]) - admitted})
         if self._mega is not None:
             # deferred mode: the host rings above stay authoritative;
             # the device replays the identical admission at flush
@@ -600,13 +621,19 @@ class DataplaneRuntime:
         """Pipeline stage 1 (dispatch): pop up to ``batch`` rows per queue
         and issue the workers asynchronously; stage 3 (retire) runs for
         the oldest tick once more than ``pipeline_depth`` are in flight."""
+        with self.spans.span("dp.tick"):
+            return self._tick()
+
+    def _tick(self) -> int:
+        sp = self.spans
         if (self._faults is not None
                 and not self._faults.responsive(0, self._tick_count)):
             # injected stall: the tick elapses but the host serves
             # nothing — pending epochs stay queued, rings keep backlog
             self._tick_count += 1
             return 0
-        self._tick_boundary()
+        with sp.span("dp.tick.control"):
+            self._tick_boundary()
         self._tick_count += 1
         self.telemetry.runtime_ticks += 1
         if self._mega is not None:
@@ -614,29 +641,44 @@ class DataplaneRuntime:
             # order / counters), run the compute on device at flush —
             # ``pipeline_depth`` is superseded by the scan window
             return self._mega.stage_tick()
-        popped = [ring.pop(self.batch) for ring in self.rings]
+        with sp.span("dp.tick.pop") as pop:
+            popped = [ring.pop(self.batch) for ring in self.rings]
         counts = [rows.shape[0] for rows, _ in popped]
         total = sum(counts)
         if total == 0:
             return 0
-        t0 = time.perf_counter()
+        if sp.enabled:
+            t = pop.ended_s()
+            sp.count("dp.rows_popped", total)
+            sp.count("dp.ring_wait_ns", round(
+                sum(float((t - ts).sum()) for _, ts in popped) * 1e9))
         if self.fanout == "loop":
             results = {}
             for q, (rows, _) in enumerate(popped):
                 if counts[q] == 0:
                     continue
-                results[q] = pipeline.packet_step(
-                    self.bank, jnp.asarray(self._pad(rows)),
-                    **self._step_kwargs())
+                with sp.span("dp.tick.pad"):
+                    padded = self._pad(rows)
+                with sp.span("dp.tick.h2d"):
+                    x = jnp.asarray(padded)
+                with sp.span("dp.tick.launch"):
+                    results[q] = pipeline.packet_step(
+                        self.bank, x, **self._step_kwargs())
+                sp.count("dp.kernel_rows", self._kernel_rows)
         else:
-            qstack = np.stack([self._pad(rows) for rows, _ in popped])
-            res_all = self._vstep(self.bank, jnp.asarray(qstack))
+            with sp.span("dp.tick.pad"):
+                qstack = np.stack([self._pad(rows) for rows, _ in popped])
+            with sp.span("dp.tick.h2d"):
+                x = jnp.asarray(qstack)
+            with sp.span("dp.tick.launch"):
+                res_all = self._vstep(self.bank, x)
+            sp.count("dp.kernel_rows", self._kernel_rows * self.num_queues)
             results = {
                 q: pipeline.PacketResult(*(leaf[q] for leaf in res_all))
                 for q in range(self.num_queues) if counts[q]
             }
         self._inflight.append(_InFlight(
-            self._tick_count, popped, counts, results, self.bank, t0))
+            self._tick_count, popped, counts, results, self.bank))
         while len(self._inflight) > self.pipeline_depth - 1:
             self._retire(self._inflight.popleft())
         return total
@@ -644,50 +686,48 @@ class DataplaneRuntime:
     def _retire(self, rec: _InFlight) -> None:
         """Pipeline stage 3: block on the tick's device work, then fold
         results into telemetry / audit / record and retire ring rows."""
-        total = sum(rec.counts)
-        for res in rec.results.values():
-            res.scores.block_until_ready()
-        now = time.perf_counter()
-        # busy time must not double-count overlapping in-flight windows:
-        # charge this tick only for the span since the previous retire
-        # (identical to dispatch->retire when the pipeline is synchronous)
-        start = (rec.t0 if self._last_retire_s is None
-                 else max(rec.t0, self._last_retire_s))
-        tick_s = now - start
-        self._last_retire_s = now
+        sp = self.spans
+        with sp.span("dp.retire.wait") as wait:
+            for res in rec.results.values():
+                res.scores.block_until_ready()
+        now = wait.ended_s()
         for q, res in rec.results.items():
             n = rec.counts[q]
             rows, ts = rec.popped[q]
-            slots = np.asarray(res.slots)[:n]
-            verdicts = np.asarray(res.verdicts)[:n]
-            actions = np.asarray(res.actions)[:n]
+            with sp.span("dp.retire.d2h"):
+                slots = np.asarray(res.slots)[:n]
+                verdicts = np.asarray(res.verdicts)[:n]
+                actions = np.asarray(res.actions)[:n]
             if self.on_retire is not None:
-                self.on_retire(q, rows, slots, verdicts, actions, rec.tick)
-            self.telemetry.record_tick(
-                q, slots, verdicts, actions,
-                latency_us=(now - ts) * 1e6,
-                tick_s=tick_s * n / total,
-            )
-            self.rings[q].mark_completed(n)
+                with sp.span("dp.retire.tap"):
+                    self.on_retire(q, rows, slots, verdicts, actions,
+                                   rec.tick)
+            with sp.span("dp.retire.telemetry"):
+                self.telemetry.record_tick(
+                    q, slots, verdicts, actions,
+                    latency_us=(now - ts) * 1e6)
+                self.rings[q].mark_completed(n)
             if self.audit:
                 # audit against the bank version this tick was dispatched
                 # with — a later epoch must not invalidate earlier work
-                exact = pipeline.packet_step(
-                    rec.bank, jnp.asarray(self._pad(rows)),
-                    num_slots=self.num_slots, strategy="take",
-                    backend=self.backend)
-                bad = (np.asarray(exact.verdicts)[:n] != verdicts).sum()
-                bad += (np.asarray(exact.slots)[:n] != slots).sum()
-                self.telemetry.wrong_verdict += int(bad)
+                with sp.span("dp.retire.audit"):
+                    exact = pipeline.packet_step(
+                        rec.bank, jnp.asarray(self._pad(rows)),
+                        num_slots=self.num_slots, strategy="take",
+                        backend=self.backend)
+                    bad = (np.asarray(exact.verdicts)[:n] != verdicts).sum()
+                    bad += (np.asarray(exact.slots)[:n] != slots).sum()
+                    self.telemetry.wrong_verdict += int(bad)
             if self._record:
                 self.completed_seq[q].extend(int(s) for s in rows[:, SEQ_WORD])
                 self.completed_verdicts[q].extend(bool(v) for v in verdicts)
                 self.completed_slots[q].extend(int(s) for s in slots)
         self.telemetry.touch(now)
         if self.telemetry.has_sink:
-            self.telemetry.emit_delta(
-                tick=rec.tick, now=now,
-                depths=[len(r) for r in self.rings])
+            with sp.span("dp.retire.telemetry"):
+                self.telemetry.emit_delta(
+                    tick=rec.tick, now=now,
+                    depths=[len(r) for r in self.rings])
 
     def retire_all(self) -> None:
         """Flush the pipeline: retire every in-flight tick (oldest first).

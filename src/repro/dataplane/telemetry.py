@@ -47,7 +47,6 @@ class QueueTelemetry:
         self.ticks = 0
         self.completed = 0
         self.dropped = 0  # ring-edge drops charged to this queue
-        self.busy_s = 0.0
         self.per_slot_total = np.zeros(num_slots, np.int64)
         self.per_slot_malicious = np.zeros(num_slots, np.int64)
         self.actions = np.zeros(3, np.int64)  # forward / drop / flag
@@ -55,14 +54,13 @@ class QueueTelemetry:
         self.latency_sum_us = 0.0
         self.latency_max_us = 0.0
 
-    def record(self, slots, verdicts, actions, latency_us, tick_s: float) -> None:
+    def record(self, slots, verdicts, actions, latency_us) -> None:
         slots = np.asarray(slots)
         verdicts = np.asarray(verdicts, bool)
         actions = np.asarray(actions)
         latency_us = np.asarray(latency_us, np.float64)
         self.ticks += 1
         self.completed += len(slots)
-        self.busy_s += tick_s
         np.add.at(self.per_slot_total, slots, 1)
         np.add.at(self.per_slot_malicious, slots[verdicts], 1)
         for a in (pkt.ACTION_FORWARD, pkt.ACTION_DROP, pkt.ACTION_FLAG):
@@ -73,19 +71,17 @@ class QueueTelemetry:
             self.latency_max_us = max(self.latency_max_us, float(latency_us.max()))
 
     def record_bulk(self, *, ticks: int, completed: int, per_slot_total,
-                    per_slot_malicious, actions, latency_us,
-                    busy_s: float) -> None:
+                    per_slot_malicious, actions, latency_us) -> None:
         """Fold a whole megastep window of device-accumulated counters in
         one call (DESIGN.md §13): the scan carries per-queue completed /
         served-tick / per-slot / action counters on device and the flush
         drains them here in bulk — totals are bit-identical to ``ticks``
-        sequential ``record`` calls; only wall-clock attribution
-        (``busy_s``, latencies) differs, measured at flush granularity.
+        sequential ``record`` calls; only the latencies differ, measured
+        at flush granularity.
         """
         latency_us = np.asarray(latency_us, np.float64)
         self.ticks += int(ticks)
         self.completed += int(completed)
-        self.busy_s += busy_s
         self.per_slot_total += np.asarray(per_slot_total, np.int64)
         self.per_slot_malicious += np.asarray(per_slot_malicious, np.int64)
         self.actions += np.asarray(actions, np.int64)
@@ -111,8 +107,6 @@ class QueueTelemetry:
             "ticks": self.ticks,
             "completed": self.completed,
             "dropped": self.dropped,
-            "busy_s": self.busy_s,
-            "pps_busy": self.completed / self.busy_s if self.busy_s else 0.0,
             "per_slot_total": self.per_slot_total.tolist(),
             "per_slot_malicious": self.per_slot_malicious.tolist(),
             "actions": {
@@ -168,8 +162,8 @@ class Telemetry:
         self.window_last_s = now
 
     def record_tick(self, queue: int, slots, verdicts, actions,
-                    latency_us, tick_s: float) -> None:
-        self.queues[queue].record(slots, verdicts, actions, latency_us, tick_s)
+                    latency_us) -> None:
+        self.queues[queue].record(slots, verdicts, actions, latency_us)
 
     def record_window(self, queue: int, **kw) -> None:
         """Bulk-fold one queue's megastep window (``QueueTelemetry.record_bulk``)."""
@@ -288,7 +282,6 @@ def _copy_queue(src: QueueTelemetry, queue: int) -> QueueTelemetry:
     out.ticks = src.ticks
     out.completed = src.completed
     out.dropped = src.dropped
-    out.busy_s = src.busy_s
     out.per_slot_total = src.per_slot_total.copy()
     out.per_slot_malicious = src.per_slot_malicious.copy()
     out.actions = src.actions.copy()

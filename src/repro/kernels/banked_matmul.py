@@ -108,6 +108,7 @@ def banked_matmul(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, h), x.dtype),
         interpret=interpret,
+        name="banked_matmul",
     )(block_slots, x, w, b)
 
 
@@ -174,4 +175,5 @@ def banked_xnor_layer1(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, h), jnp.float32),
         interpret=interpret,
+        name="banked_xnor_layer1",
     )(block_slots, x_packed, bank_w1, bank_b1)

@@ -93,4 +93,5 @@ def xnor_matmul(
         out_specs=pl.BlockSpec((block_b, block_h), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, h), jnp.int32),
         interpret=interpret,
+        name="xnor_matmul",
     )(x_packed, w_packed)

@@ -240,6 +240,7 @@ def fused_forward(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="fused_forward",
     )(*operands)
     return tuple(out) if with_actions else out[0]
 

@@ -75,11 +75,18 @@ from repro.dataplane import (DataplaneRuntime, MeshDataplane, faults,
                              workloads)
 from repro.kernels import ops
 from repro.launch.cache import enable_compile_cache
+from repro.obs.spans import tick_summary
+
+
+def _shards(rt) -> list:
+    """The single-host runtimes behind ``rt``: itself, or a mesh's shards."""
+    return getattr(rt, "shards", [rt])
 
 
 def _print_run_report(rt, reports, hosts: int, queues_per_host: int) -> dict:
     """Shared tail of both the play and replay paths: per-phase table,
-    telemetry, conservation, epoch log.  Returns the snapshot."""
+    telemetry, the tick loop's host spans, conservation, epoch log.
+    Returns the snapshot."""
     print(f"{'phase':<16}{'offered':>9}{'done':>9}{'dropped':>9}"
           f"{'wrong':>7}{'kpps':>10}")
     for r in reports:
@@ -94,10 +101,20 @@ def _print_run_report(rt, reports, hosts: int, queues_per_host: int) -> dict:
                  f"queue {q['queue'] % queues_per_host}"
                  if hosts > 1 else f"queue {q['queue']}")
         print(f"{label}: completed={q['completed']} "
-              f"pps_busy={q['pps_busy']:.0f} "
               f"lat p50/p99/max={q['latency_p50_us']:.0f}/"
               f"{q['latency_p99_us']:.0f}/{q['latency_max_us']:.0f}us "
               f"per_slot={q['per_slot_total']}")
+    snap["tick_spans"] = []
+    for h, shard in enumerate(_shards(rt)):
+        ts = tick_summary(shard.spans.snapshot())
+        snap["tick_spans"].append(ts)
+        if ts is None:
+            continue
+        parts = " ".join(f"{k.removeprefix('dp.')}={v:.0f}"
+                         for k, v in ts["self_us_per_tick"].items())
+        print(f"{f'host {h} ' if hosts > 1 else ''}tick: {ts['ticks']} "
+              f"ticks, mean {ts['mean_us']:.0f} us, longest "
+              f"{ts['max_us']:.0f} us; self us/tick {parts}")
     aud = snap["conservation"]
     print(f"conservation: offered={aud['totals']['offered']} = "
           f"completed={aud['totals']['completed']} + "
@@ -223,6 +240,8 @@ def _replay_main(args) -> dict:
           f"{hosts} host(s) x {queues} queue(s)")
     rt = workloads.make_runtime(trace, audit=args.audit,
                                 megastep_ticks=args.megastep_ticks)
+    for shard in _shards(rt):
+        shard.spans.enable()
     observer = _start_observer(rt, args,
                                num_slots=int(meta.get("num_slots") or 4))
     rep = workloads.replay(trace, rt)
@@ -479,6 +498,8 @@ def main(argv=None) -> dict:
     else:
         rt = DataplaneRuntime(bank, num_queues=args.queues, **kw)
         shape = f"{args.queues} queues"
+    for shard in _shards(rt):
+        shard.spans.enable()
     print(f"runtime: {shape} x batch {args.batch}, "
           f"strategy={args.strategy}, "
           f"ring={args.ring_capacity}, depth={rt.pipeline_depth}, "

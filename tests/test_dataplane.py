@@ -10,6 +10,7 @@ from _hyp import given, settings, st
 from repro.core import bank as bank_lib, executor, packet as pkt, switching
 from repro.dataplane import (DataplaneRuntime, PacketRing, Phase,
                              emergency_phases, play, render, rss, scenarios)
+from repro.obs import spans
 
 
 @pytest.fixture(scope="module")
@@ -220,16 +221,23 @@ def test_runtime_failover_drains_dead_queue(bank2):
 
 
 def test_telemetry_snapshot(bank2):
-    rt = run_trace(bank2, small_trace(seed=2), strategy="fused",
-                   ring_capacity=4096)
+    rt = DataplaneRuntime(bank2, num_queues=4, batch=32, ring_capacity=4096,
+                          record=True, strategy="fused")
+    rt.spans.enable()
+    play(rt, small_trace(seed=2))
     snap = rt.snapshot()
     assert snap["completed_total"] == sum(
         q["completed"] for q in snap["queues"])
     assert snap["slot_swaps"] == 1 and snap["reta_updates"] >= 2
     busy = [q for q in snap["queues"] if q["completed"]]
     assert busy
+    # the tick loop's span summary replaces the old per-queue busy rate
+    ticks = spans.tick_summary(rt.spans.snapshot())
+    assert ticks["ticks"] >= max(q["ticks"] for q in busy)
+    assert 0 < ticks["mean_us"] <= ticks["max_us"]
+    assert rt.spans.snapshot()["spans"]["dp.tick.launch"]["count"] == sum(
+        q["ticks"] for q in busy)
     for q in busy:
-        assert q["pps_busy"] > 0
         assert q["latency_p50_us"] <= q["latency_p99_us"]
         assert sum(q["per_slot_total"]) == q["completed"]
         acts = q["actions"]

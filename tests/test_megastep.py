@@ -71,7 +71,7 @@ def _drive(bank, bursts, epochs, megastep_ticks, *, audit=True,
 def _observed(rt) -> tuple:
     """Everything the bit-exactness contract covers, as one comparable
     value: per-queue completion streams, counting telemetry, epoch apply
-    ticks.  (Wall-clock fields — busy_s, latency — are excluded.)"""
+    ticks.  (Wall-clock fields — latency — are excluded.)"""
     queues = []
     for q, qs in enumerate(rt.snapshot()["queues"]):
         queues.append((

@@ -357,11 +357,9 @@ def test_mesh_policy_never_routes_onto_failed_pairs(bank2):
 def test_telemetry_merge_aggregates_hosts():
     t0, t1 = telemetry.Telemetry(2, 2), telemetry.Telemetry(2, 2)
     t0.record_tick(0, np.array([0, 1]), np.array([True, False]),
-                   np.array([0, 1]), latency_us=np.array([10.0, 20.0]),
-                   tick_s=0.5)
+                   np.array([0, 1]), latency_us=np.array([10.0, 20.0]))
     t1.record_tick(1, np.array([1, 1, 0]), np.array([True, True, False]),
-                   np.array([2, 0, 0]), latency_us=np.array([5.0, 6.0, 7.0]),
-                   tick_s=0.25)
+                   np.array([2, 0, 0]), latency_us=np.array([5.0, 6.0, 7.0]))
     t0.slot_swaps, t1.wrong_verdict = 2, 3
     merged = telemetry.merge([t0, t1])
     assert len(merged.queues) == 4              # host-major global order

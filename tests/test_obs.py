@@ -200,7 +200,7 @@ def test_merge_carries_event_counters_and_aligns_windows():
     a.record_drops(0, 5, now=10.0)
     b.record_drops(1, 7, now=10.5)
     a.queues[0].record(np.array([0, 1]), np.array([False, False]),
-                       np.array([0, 0]), np.array([1.0, 1.0]), 0.01)
+                       np.array([0, 0]), np.array([1.0, 1.0]))
     a.touch(18.0)   # a covered 10.0 .. 18.0
     b.touch(11.0)   # b covered 10.5 .. 11.0 (crashed early)
     m = telemetry_mod.merge([a, b])
@@ -456,8 +456,12 @@ def test_streaming_recorder_bounds_buffering(bank2, tmp_path):
 def test_cli_epoch_log_json(tmp_path, capsys):
     from repro.launch import dataplane as launch
     out = tmp_path / "epochs.json"
-    launch.main(["--scenario", "emergency", "--queues", "2", "--slots", "2",
-                 "--ring-capacity", "2048", "--epoch-log-json", str(out)])
+    snap = launch.main(["--scenario", "emergency", "--queues", "2",
+                        "--slots", "2", "--ring-capacity", "2048",
+                        "--epoch-log-json", str(out)])
+    # the CLI turns the tick loop's spans on and reports them
+    assert snap["tick_spans"][0]["ticks"] == snap["runtime_ticks"]
+    assert "tick: " in capsys.readouterr().out
     doc = json.loads(out.read_text())
     assert doc["epochs"], "no epochs in log"
     assert doc["continuity"]["ok"]
@@ -465,3 +469,164 @@ def test_cli_epoch_log_json(tmp_path, capsys):
         assert "span" in rec and "commands" in rec
     assert doc["stats"]["epochs_applied"] >= len(
         [r for r in doc["epochs"] if r["commit_mode"] == "atomic"])
+
+
+# ---------------------------------------------------------------------------
+# host spans of the tick loop (HostSpans)
+# ---------------------------------------------------------------------------
+
+class _FakeClock:
+    """Integer nanoseconds handed out from a script; counts its reads."""
+
+    def __init__(self, times=()):
+        self.times = list(times)
+        self.reads = 0
+
+    def __call__(self):
+        self.reads += 1
+        return self.times.pop(0)
+
+
+def test_host_spans_disabled_records_nothing():
+    clock = _FakeClock()
+    rec = spans.HostSpans(clock=clock)
+    a, b = rec.span("dp.tick"), rec.span("dp.tick.pop")
+    assert a is b                       # one shared no-op context
+    with a:
+        with b:
+            rec.count("dp.rows_popped", 5)
+    assert clock.reads == 0
+    assert rec.snapshot() == {"spans": {}, "counters": {},
+                              "slowest_ticks": []}
+    assert spans.tick_summary(rec.snapshot()) is None
+
+
+def test_host_spans_self_time_nests():
+    # tick [0, 100]: pop [10, 30], launch [40, 90] holding h2d [50, 60]
+    clock = _FakeClock([0, 10, 30, 40, 50, 60, 90, 100])
+    rec = spans.HostSpans(clock=clock)
+    rec.enable()
+    with rec.span("dp.tick"):
+        with rec.span("dp.tick.pop"):
+            pass
+        with rec.span("dp.tick.launch"):
+            with rec.span("dp.tick.h2d"):
+                pass
+    s = rec.snapshot()["spans"]
+    assert s["dp.tick"] == {"count": 1, "total_ns": 100, "self_ns": 30,
+                            "max_ns": 100}
+    assert s["dp.tick.pop"]["self_ns"] == 20
+    assert (s["dp.tick.launch"]["total_ns"],
+            s["dp.tick.launch"]["self_ns"]) == (50, 40)
+    assert s["dp.tick.h2d"]["self_ns"] == 10
+    (tick,) = rec.snapshot()["slowest_ticks"]
+    assert tick == {"total_ns": 100, "self_ns": {
+        "dp.tick": 30, "dp.tick.pop": 20, "dp.tick.launch": 40,
+        "dp.tick.h2d": 10}}
+    # the self times of a tick add up to its total
+    assert sum(tick["self_ns"].values()) == tick["total_ns"]
+
+
+def test_host_spans_keep_the_longest_ticks_bounded():
+    times = []
+    t = 0
+    for d in range(1, 21):       # 20 ticks of 1..20 ns, each with a pop
+        times += [t, t, t + d, t + d]
+        t += 100
+    rec = spans.HostSpans(clock=_FakeClock(times))
+    rec.enable()
+    for _ in range(20):
+        with rec.span("dp.tick"):
+            with rec.span("dp.tick.pop"):
+                pass
+    kept = rec.snapshot()["slowest_ticks"]
+    assert len(kept) == spans.HostSpans.LONGEST == 8
+    assert [k["total_ns"] for k in kept] == list(range(20, 12, -1))
+    assert all(k["self_ns"] == {"dp.tick.pop": k["total_ns"], "dp.tick": 0}
+               for k in kept)
+    assert rec.snapshot()["spans"]["dp.tick"]["max_ns"] == 20
+    rec.reset()
+    assert rec.snapshot()["slowest_ticks"] == []
+
+
+def test_host_spans_counters_add_up():
+    rec = spans.HostSpans()
+    rec.enable()
+    for n in (3, 4, 5):
+        rec.count("dp.rows_popped", n)
+    rec.count("dp.kernel_rows", 160)
+    assert rec.snapshot()["counters"] == {"dp.rows_popped": 12,
+                                          "dp.kernel_rows": 160}
+    rec.reset()
+    assert rec.snapshot()["counters"] == {}
+
+
+def test_runtime_tick_spans_once_per_nonempty_queue(bank2):
+    rt = DataplaneRuntime(bank2, num_queues=4, batch=32, ring_capacity=256)
+    rt.on_retire = lambda *a: None
+    rt.spans.enable()
+    rng = np.random.default_rng(0)
+    queues = np.array([0] * 5 + [1] * 7 + [3] * 2)   # queue 2 stays empty
+    rt.dispatch(_packets(rng, len(queues)), queues=queues)
+    assert rt.tick() == len(queues)
+    snap = rt.spans.snapshot()
+    counts = {k: v["count"] for k, v in snap["spans"].items()}
+    per_queue = ("dp.tick.pad", "dp.tick.h2d", "dp.tick.launch",
+                 "dp.retire.d2h", "dp.retire.tap", "dp.retire.telemetry")
+    assert {k: counts[k] for k in per_queue} == dict.fromkeys(per_queue, 3)
+    for name in ("dp.dispatch", "dp.tick", "dp.tick.control", "dp.tick.pop",
+                 "dp.retire.wait"):
+        assert counts[name] == 1, name
+    assert "dp.retire.audit" not in counts       # audit is off
+    assert "dp.dispatch.hash" not in counts      # queue ids were given
+    assert counts["dp.dispatch.push"] == 1
+    assert snap["counters"]["dp.rows_popped"] == len(queues)
+    assert snap["counters"]["dp.ring_wait_ns"] > 0
+    assert snap["counters"]["dp.kernel_rows"] == 3 * rt._kernel_rows
+    # every span of the tick ran inside it
+    (tick,) = snap["slowest_ticks"]
+    assert set(tick["self_ns"]) == {k for k in counts
+                                    if k.startswith(("dp.tick",
+                                                     "dp.retire."))}
+
+
+@pytest.mark.parametrize("num_slots", [1, 16])
+def test_kernel_rows_counter_is_the_padded_grouping(num_slots):
+    import jax.numpy as jnp
+    from repro.core import bank as bank_lib
+    bank = executor.init_bank(jax.random.PRNGKey(1), num_slots)
+    rt = DataplaneRuntime(bank, num_queues=1, batch=128, block_b=32)
+    rt.spans.enable()
+    rng = np.random.default_rng(num_slots)
+    rt.dispatch(_packets(rng, 128, num_slots), queues=np.zeros(128, int))
+    rt.tick()
+    b_pad = bank_lib.group_by_slot_padded(
+        jnp.zeros(128, jnp.int32), num_slots, 32).b_pad
+    assert b_pad == {1: 160, 16: 640}[num_slots]
+    assert rt.spans.snapshot()["counters"]["dp.kernel_rows"] == b_pad
+
+
+def test_host_spans_reach_the_profiler_trace(bank2, tmp_path):
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    rt = DataplaneRuntime(bank2, num_queues=2, batch=32)
+    rng = np.random.default_rng(2)
+    rt.dispatch(_packets(rng, 16))
+    rt.tick()                                    # compile outside the trace
+    rt.spans.enable(annotate=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        rt.dispatch(_packets(rng, 16))
+        rt.tick()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                        recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events}
+    recorded = set(rt.spans.snapshot()["spans"])
+    assert {"dp.dispatch", "dp.tick", "dp.tick.launch",
+            "dp.retire.wait"} <= recorded
+    assert recorded <= names
