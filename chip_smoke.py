@@ -215,13 +215,13 @@ def four_chips(clock):
         streams[fanout] = (rt.completed_seq, rt.completed_slots,
                            rt.completed_verdicts)
         if fanout == "shard_map":
-            probe = rt._vstep(rt.bank, jnp.zeros(
+            probe = rt._step(rt.bank, jnp.zeros(
                 (QUEUES, BATCH, pkt.PACKET_WORDS), jnp.uint32))
-            placed = {s.device for s in probe.verdicts.addressable_shards}
+            placed = {s.device for s in probe.addressable_shards}
             _check(placed == set(jax.devices()),
                    f"shard_map results on {len(placed)} device(s)")
             print(f"shard_map: results sharded over {len(placed)} devices "
-                  f"({probe.verdicts.sharding})")
+                  f"({probe.sharding})")
     n = sum(len(q) for q in streams["loop"][0])
     _check(streams["loop"] == streams["shard_map"],
            "slot/verdict streams differ between loop and shard_map")

@@ -102,6 +102,35 @@ def packet_step(
     return PacketResult(slots, scores, scores > 0.0, actions)
 
 
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_slots", "strategy", "backend", "block_b"),
+)
+def packet_step_queues(
+    bank,
+    packets: jnp.ndarray,  # (Q, B, 272) uint32: every queue's padded batch
+    *,
+    num_slots: int,
+    strategy: str = "take",
+    backend: str = "auto",
+    block_b: int = 256,
+) -> jnp.ndarray:
+    """Every queue's batch through ONE ``packet_step``, packed for one pull.
+
+    A row's slot, score, verdict and action depend only on that row and
+    the bank, so the flat ``(Q * B)``-row step gives bit-identical
+    per-row results to ``Q`` launches of ``B`` rows.  Returns
+    ``(Q, 3, B)`` int32: queue ``q``'s slots, verdicts (0/1) and actions
+    at ``[q, 0]``, ``[q, 1]`` and ``[q, 2]``.  Scores stay on the device.
+    """
+    q, b, words = packets.shape
+    res = packet_step(bank, packets.reshape(q * b, words),
+                      num_slots=num_slots, strategy=strategy,
+                      backend=backend, block_b=block_b)
+    return jnp.stack([res.slots, res.verdicts.astype(jnp.int32),
+                      res.actions], axis=0).reshape(3, q, b).swapaxes(0, 1)
+
+
 @functools.partial(jax.jit, static_argnames=("backend",))
 def slot_select_only(packets: jnp.ndarray, num_slots: int, *, backend="auto"):
     """Isolated sigma for the Fig. 4 / Fig. 5 microbenchmarks."""

@@ -4,8 +4,9 @@ This is the repo's analogue of the paper's AF_XDP deployment shape: the
 NIC hashes each flow to one of N queues (``rss``), every queue buffers
 into a bounded ring (``ring``), and each queue drains through the *same*
 resident-bank forwarding program (`repro.core.pipeline.packet_step`) —
-one fused launch per queue-block, per-queue FIFO ordering, and online
-slot swaps that never produce a wrong verdict.
+one launch over every queue's rows per tick, one packed result pulled
+once, per-queue FIFO ordering, and online slot swaps that never produce
+a wrong verdict.
 
 Control plane (DESIGN.md §7): every runtime mutation — slot swap, RETA
 rewrite, queue fail/restore, policy change — flows through
@@ -17,26 +18,30 @@ version it was dispatched with; the legacy ``swap_slot``/``set_reta``/
 epochs.  An installed ``RoutingPolicy`` is consulted at every tick
 boundary and its rebalances land as ordinary ``ProgramReta`` epochs.
 
-Fan-out modes (``fanout=``):
+Each tick pads every queue's pop to ``batch`` rows in one (Q, B, 272)
+host batch (an empty queue's rows are zeros and its results are
+discarded), copies it to the device once, and runs
+`repro.core.pipeline.packet_step_queues`: ONE ``packet_step`` over the
+flat (Q * B)-row batch, whose slots, verdicts and actions come back
+packed in one (Q, 3, B) int32 array — one block and one pull per tick.
+The step is row-independent, so this equals a launch per queue bit for
+bit.  Fan-out modes (``fanout=``) only split that work:
 
-* ``loop``      — one jitted ``packet_step`` call per non-empty queue per
-                  tick.  The default for the fused strategy: the
-                  structural audit can assert exactly ONE Pallas launch
-                  per queue-block.
-* ``vmap``      — queue batches stacked to (Q, B, 272) and processed by a
-                  single vmapped program; best for the gather strategies
-                  on one device.
-* ``shard_map`` — the vmapped program sharded over a device mesh (reusing
-                  `repro.launch.mesh.make_host_mesh`), so queues map onto
-                  devices exactly like RSS maps flows onto NIC queues.
+* ``vmap``      — the one flat launch on one device (``auto`` picks it
+                  for every strategy).
+* ``shard_map`` — the same flat step as each shard's body, queues sharded
+                  over a device mesh (`repro.launch.mesh.make_queue_mesh`)
+                  exactly like RSS maps flows onto NIC queues.
                   Host-simulated on 1-device CPU CI; real spread on TPU.
-* ``auto``      — ``loop`` for fused/grouped strategies, ``vmap`` else.
+* ``loop``      — one launch and one pull per non-empty queue: kept only
+                  as the per-queue reference the parity tests compare
+                  the flat launch with.
 
 The tick loop is a 3-stage pipeline (dispatch / device / retire) with a
 bounded in-flight window of ``pipeline_depth`` ticks, the multi-queue
 form of ``switching.replay_trace(stream=True)``: each ``tick()`` pops at
 most ``batch`` rows per queue, pads to the static batch shape (no
-recompiles), issues the workers asynchronously, and retires the oldest
+recompiles), issues the launch asynchronously, and retires the oldest
 tick once the window is full.  ``pipeline_depth=1`` degenerates to the
 synchronous loop; any depth produces bit-identical verdicts because
 every tick captures the bank/RETA version current at its dispatch.
@@ -46,15 +51,17 @@ across every control command kind, not just slot swaps.
 
 ``self.spans`` (`repro.obs.spans.HostSpans`, off until enabled) names
 each host step of the sequential engine: ``dp.dispatch`` (``.hash``,
-``.push``), ``dp.tick`` (``.control``, ``.pop``, then per queue ``.pad``,
-``.h2d``, ``.launch``) and the retire (``dp.retire.wait``, ``.d2h``,
-``.tap``, ``.telemetry``, ``.audit``), with the counters
-``dp.rows_popped``, ``dp.ring_wait_ns`` and ``dp.kernel_rows``.
+``.push``), ``dp.tick`` (``.control``, ``.pop``, ``.pad``, then per
+launch ``.h2d`` and ``.launch``) and the retire (``dp.retire.wait``, per
+launch ``.d2h``, per queue ``.tap``, ``.telemetry``, ``.audit``), with
+the counters ``dp.rows_popped``, ``dp.ring_wait_ns``, ``dp.kernel_rows``
+and ``dp.queue_batches`` (non-empty queue batches served).
 """
 
 from __future__ import annotations
 
 import collections
+import functools
 import time
 import warnings
 
@@ -75,7 +82,8 @@ from repro.kernels import ops as _ops
 from repro.launch import mesh as mesh_lib
 from repro.obs.spans import HostSpans
 
-_LOOP_STRATEGIES = ("fused", "grouped", "grouped_staged")
+# strategies whose kernel pads every slot's segment to whole blocks
+_GROUPED_STRATEGIES = ("fused", "grouped", "grouped_staged")
 
 _DEPRECATION = ("%s() is a deprecation shim: submit a %s command through "
                 "runtime.control.submit(...) instead")
@@ -154,13 +162,14 @@ def drain_rings(rt, max_ticks: int = 100_000) -> int:
 class _InFlight:
     """One dispatched-but-unretired tick (the device stage of the pipeline)."""
 
-    __slots__ = ("tick", "popped", "counts", "results", "bank")
+    __slots__ = ("tick", "popped", "counts", "batch", "launches", "bank")
 
-    def __init__(self, tick, popped, counts, results, bank):
+    def __init__(self, tick, popped, counts, batch, launches, bank):
         self.tick = tick
         self.popped = popped      # [(rows, ts)] per queue
         self.counts = counts      # rows popped per queue
-        self.results = results    # {queue: PacketResult} (async)
+        self.batch = batch        # (Q, B, words) padded host batch
+        self.launches = launches  # [(queues, (len(queues), 3, B) i32)] async
         self.bank = bank          # bank version captured at dispatch
 
 
@@ -251,16 +260,11 @@ class DataplaneRuntime:
         self.failed_queues: set[int] = set()
         self.bucket_load = np.zeros(len(self.reta), np.int64)
         if fanout == "auto":
-            fanout = "loop" if strategy in _LOOP_STRATEGIES else "vmap"
+            fanout = "vmap"
         if fanout not in ("loop", "vmap", "shard_map"):
             raise ValueError(f"unknown fanout {fanout!r}")
         self.fanout = fanout
-        self._vstep = None if fanout == "loop" else self._build_fanout(fanout)
-        # rows one launch computes per queue: the slot-grouped strategies
-        # pad every slot's segment to whole blocks
-        self._kernel_rows = (
-            bank_lib.padded_rows(self.batch, self.num_slots, self.block_b)
-            if strategy in _LOOP_STRATEGIES else self.batch)
+        self._step, self._kernel_rows = self._build_fanout(fanout)
         if megastep_ticks < 1:
             raise ValueError("megastep_ticks must be >= 1")
         self.megastep_ticks = int(megastep_ticks)
@@ -288,24 +292,27 @@ class DataplaneRuntime:
 
     # -- worker construction ------------------------------------------------
 
-    def _step_kwargs(self) -> dict:
-        return dict(num_slots=self.num_slots, strategy=self.strategy,
-                    backend=self.backend, block_b=self.block_b)
-
     def _build_fanout(self, fanout: str):
-        kw = self._step_kwargs()
-
-        def per_queue(bank, qpackets):  # (Qlocal, B, 272) -> PacketResult
-            return jax.vmap(
-                lambda p: pipeline.packet_step(bank, p, **kw))(qpackets)
-
-        if fanout == "vmap":
-            return jax.jit(per_queue)
-        mesh, axis = queue_mesh(self.num_queues)
-        return jax.jit(jax.shard_map(
-            per_queue, mesh=mesh,
-            in_specs=(P(), P(axis)), out_specs=P(axis), check_vma=False,
-        ))
+        """The step one launch runs, (Qlaunch, B, 272) -> (Qlaunch, 3, B)
+        packed results, and the kernel rows that launch computes."""
+        step = functools.partial(
+            pipeline.packet_step_queues, num_slots=self.num_slots,
+            strategy=self.strategy, backend=self.backend,
+            block_b=self.block_b)
+        queues, shards = self.num_queues, 1
+        if fanout == "loop":
+            queues = 1
+        elif fanout == "shard_map":
+            mesh, axis = queue_mesh(self.num_queues)
+            shards = mesh.shape[axis]
+            step = jax.jit(jax.shard_map(
+                step, mesh=mesh,
+                in_specs=(P(), P(axis)), out_specs=P(axis), check_vma=False,
+            ))
+        rows = queues // shards * self.batch
+        if self.strategy in _GROUPED_STRATEGIES:
+            rows = bank_lib.padded_rows(rows, self.num_slots, self.block_b)
+        return step, shards * rows
 
     # -- control plane: command application (ControlPlane-only entry) -------
 
@@ -607,14 +614,16 @@ class DataplaneRuntime:
         return {"per_queue": per_queue,
                 "dropped": sum(p["dropped"] for p in per_queue)}
 
-    def _pad(self, rows: np.ndarray) -> np.ndarray:
-        n = rows.shape[0]
-        if n == self.batch:
-            return rows
-        out = np.zeros((self.batch, rows.shape[1]), np.uint32)
-        out[:n] = rows
-        if n:  # repeat the last valid row; results beyond n are discarded
-            out[n:] = rows[n - 1]
+    def _host_batch(self, popped) -> np.ndarray:
+        """Every queue's pop padded to ``batch`` rows in one fresh
+        (Q, B, words) array: a short queue repeats its last row, an empty
+        one is zeros.  Results past each queue's count are discarded."""
+        words = popped[0][0].shape[1]
+        out = np.empty((self.num_queues, self.batch, words), np.uint32)
+        for q, (rows, _) in enumerate(popped):
+            n = rows.shape[0]
+            out[q, :n] = rows
+            out[q, n:] = rows[n - 1] if n else 0
         return out
 
     def tick(self) -> int:
@@ -652,33 +661,22 @@ class DataplaneRuntime:
             sp.count("dp.rows_popped", total)
             sp.count("dp.ring_wait_ns", round(
                 sum(float((t - ts).sum()) for _, ts in popped) * 1e9))
+        with sp.span("dp.tick.pad"):
+            batch = self._host_batch(popped)
         if self.fanout == "loop":
-            results = {}
-            for q, (rows, _) in enumerate(popped):
-                if counts[q] == 0:
-                    continue
-                with sp.span("dp.tick.pad"):
-                    padded = self._pad(rows)
-                with sp.span("dp.tick.h2d"):
-                    x = jnp.asarray(padded)
-                with sp.span("dp.tick.launch"):
-                    results[q] = pipeline.packet_step(
-                        self.bank, x, **self._step_kwargs())
-                sp.count("dp.kernel_rows", self._kernel_rows)
+            groups = [(q,) for q in range(self.num_queues) if counts[q]]
         else:
-            with sp.span("dp.tick.pad"):
-                qstack = np.stack([self._pad(rows) for rows, _ in popped])
+            groups = [tuple(range(self.num_queues))]
+        launches = []
+        for queues in groups:
             with sp.span("dp.tick.h2d"):
-                x = jnp.asarray(qstack)
+                x = jnp.asarray(batch[queues[0]:queues[-1] + 1])
             with sp.span("dp.tick.launch"):
-                res_all = self._vstep(self.bank, x)
-            sp.count("dp.kernel_rows", self._kernel_rows * self.num_queues)
-            results = {
-                q: pipeline.PacketResult(*(leaf[q] for leaf in res_all))
-                for q in range(self.num_queues) if counts[q]
-            }
+                launches.append((queues, self._step(self.bank, x)))
+            sp.count("dp.kernel_rows", self._kernel_rows)
+        sp.count("dp.queue_batches", sum(1 for n in counts if n))
         self._inflight.append(_InFlight(
-            self._tick_count, popped, counts, results, self.bank))
+            self._tick_count, popped, counts, batch, launches, self.bank))
         while len(self._inflight) > self.pipeline_depth - 1:
             self._retire(self._inflight.popleft())
         return total
@@ -688,46 +686,55 @@ class DataplaneRuntime:
         results into telemetry / audit / record and retire ring rows."""
         sp = self.spans
         with sp.span("dp.retire.wait") as wait:
-            for res in rec.results.values():
-                res.scores.block_until_ready()
+            for _, out in rec.launches:
+                out.block_until_ready()
         now = wait.ended_s()
-        for q, res in rec.results.items():
-            n = rec.counts[q]
-            rows, ts = rec.popped[q]
+        for queues, out in rec.launches:
             with sp.span("dp.retire.d2h"):
-                slots = np.asarray(res.slots)[:n]
-                verdicts = np.asarray(res.verdicts)[:n]
-                actions = np.asarray(res.actions)[:n]
-            if self.on_retire is not None:
-                with sp.span("dp.retire.tap"):
-                    self.on_retire(q, rows, slots, verdicts, actions,
-                                   rec.tick)
-            with sp.span("dp.retire.telemetry"):
-                self.telemetry.record_tick(
-                    q, slots, verdicts, actions,
-                    latency_us=(now - ts) * 1e6)
-                self.rings[q].mark_completed(n)
-            if self.audit:
-                # audit against the bank version this tick was dispatched
-                # with — a later epoch must not invalidate earlier work
-                with sp.span("dp.retire.audit"):
-                    exact = pipeline.packet_step(
-                        rec.bank, jnp.asarray(self._pad(rows)),
-                        num_slots=self.num_slots, strategy="take",
-                        backend=self.backend)
-                    bad = (np.asarray(exact.verdicts)[:n] != verdicts).sum()
-                    bad += (np.asarray(exact.slots)[:n] != slots).sum()
-                    self.telemetry.wrong_verdict += int(bad)
-            if self._record:
-                self.completed_seq[q].extend(int(s) for s in rows[:, SEQ_WORD])
-                self.completed_verdicts[q].extend(bool(v) for v in verdicts)
-                self.completed_slots[q].extend(int(s) for s in slots)
+                # a fresh host array every tick: the taps keep references
+                packed = np.asarray(out)
+            for i, q in enumerate(queues):
+                if rec.counts[q]:
+                    self._retire_queue(rec, q, packed[i], now)
         self.telemetry.touch(now)
         if self.telemetry.has_sink:
             with sp.span("dp.retire.telemetry"):
                 self.telemetry.emit_delta(
                     tick=rec.tick, now=now,
                     depths=[len(r) for r in self.rings])
+
+    def _retire_queue(self, rec: _InFlight, q: int, packed: np.ndarray,
+                      now: float) -> None:
+        """Fold queue ``q``'s rows of a retired tick (``packed``: its
+        (3, B) slots / verdicts / actions) into taps, telemetry, audit
+        and record, and retire its ring rows."""
+        sp = self.spans
+        n = rec.counts[q]
+        rows, ts = rec.popped[q]
+        slots, actions = packed[0, :n], packed[2, :n]
+        verdicts = packed[1, :n] != 0
+        if self.on_retire is not None:
+            with sp.span("dp.retire.tap"):
+                self.on_retire(q, rows, slots, verdicts, actions, rec.tick)
+        with sp.span("dp.retire.telemetry"):
+            self.telemetry.record_tick(
+                q, slots, verdicts, actions, latency_us=(now - ts) * 1e6)
+            self.rings[q].mark_completed(n)
+        if self.audit:
+            # audit against the bank version this tick was dispatched
+            # with — a later epoch must not invalidate earlier work
+            with sp.span("dp.retire.audit"):
+                exact = pipeline.packet_step(
+                    rec.bank, jnp.asarray(rec.batch[q]),
+                    num_slots=self.num_slots, strategy="take",
+                    backend=self.backend)
+                bad = (np.asarray(exact.verdicts)[:n] != verdicts).sum()
+                bad += (np.asarray(exact.slots)[:n] != slots).sum()
+                self.telemetry.wrong_verdict += int(bad)
+        if self._record:
+            self.completed_seq[q].extend(int(s) for s in rows[:, SEQ_WORD])
+            self.completed_verdicts[q].extend(bool(v) for v in verdicts)
+            self.completed_slots[q].extend(int(s) for s in slots)
 
     def retire_all(self) -> None:
         """Flush the pipeline: retire every in-flight tick (oldest first).

@@ -242,10 +242,11 @@ class PacketSampler:
     # -- ingestion (tick-path: enqueue references, nothing else) -------------
     #
     # The retire tap receives arrays the runtime just created and never
-    # reuses (`ring.pop` copies out of the ring; slots/verdicts are fresh
-    # device fetches), so the tap holds references and returns — no copy,
-    # no RNG, no labeling.  The drop tap's rows are a view of the caller's
-    # dispatch buffer, so it subsamples + copies before enqueueing.
+    # reuses (`ring.pop` copies out of the ring; slots/verdicts come from
+    # the tick's fresh result pull), so the tap holds references and
+    # returns — no copy, no RNG, no labeling.  The drop tap's rows are a
+    # view of the caller's dispatch buffer, so it subsamples + copies
+    # before enqueueing.
 
     def _subsample(self, rows: np.ndarray) -> np.ndarray:
         """Indices of <= ``per_tick`` uniformly chosen rows.

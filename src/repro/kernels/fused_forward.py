@@ -270,27 +270,3 @@ def double_buffered_forward(
         x, both["w1p"], both["b1"], both["w2"], both["b2"],
         flip_slots(block_slots, active, k), row_ids, **kwargs)
 
-
-def fused_forward_qmajor(
-    x_qmajor: jnp.ndarray,     # (Q, B, meta_words + W) uint32 rows
-    bank_w1: jnp.ndarray,
-    bank_b1: jnp.ndarray,
-    bank_w2: jnp.ndarray,
-    bank_b2: jnp.ndarray,
-    block_slots: jnp.ndarray,  # (n_blocks,) i32 over the flattened batch
-    row_ids: jnp.ndarray,      # (n_blocks * block_b,) i32 into Q*B rows
-    **kwargs,
-):
-    """All queues of a host in ONE launch (the megastep's device compute).
-
-    ``x_qmajor`` stacks every queue's tick batch queue-major; flattening
-    to ``(Q * B, words)`` turns the per-queue grids into one grid whose
-    ``row_ids`` gather crosses queue boundaries freely, so a host-tick
-    costs one ``pallas_call`` regardless of queue count — instead of one
-    launch per queue-block.  Queue identity stays recoverable as
-    ``row // B``.  Accepts every ``fused_forward`` keyword.
-    """
-    q, b, words = x_qmajor.shape
-    return fused_forward(
-        x_qmajor.reshape(q * b, words), bank_w1, bank_b1, bank_w2, bank_b2,
-        block_slots, row_ids, **kwargs)

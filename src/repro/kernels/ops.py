@@ -149,16 +149,9 @@ def packet_forward_fused(
     ``packets`` are raw (B, meta_words + W) uint32 rows in arrival order;
     the kernel gathers each block's rows by DMA, slices the payload, and
     emits (scores, actions).  Returns ``(n_rows, C) f32, (n_rows,) i32``.
-
-    A 3-D ``packets`` of shape (Q, B, words) is the queue-major stacked
-    form: it is flattened so ``row_ids`` index the (Q * B) host batch and
-    ALL queues share one launch (``fused_forward_qmajor``).
     """
     backend = _resolve(backend)
-    qmajor = packets.ndim == 3
     if backend in ("ref", "mxu"):
-        if qmajor:
-            packets = packets.reshape(-1, packets.shape[-1])
         rows = jnp.take(packets, row_ids, axis=0)
         payload = rows[:, meta_words:]
         slots = _ref.expand_block_slots(block_slots, block_b, row_ids.shape[0])
@@ -166,8 +159,7 @@ def packet_forward_fused(
             bank["w1p"], bank["b1"], bank["w2"], bank["b2"], payload, slots
         )
         return scores, _fused.actions_ref(scores, rows[:, _fused.CTRL_WORD])
-    fwd = _fused.fused_forward_qmajor if qmajor else _fused.fused_forward
-    scores, actions = fwd(
+    scores, actions = _fused.fused_forward(
         packets, bank["w1p"], bank["b1"], bank["w2"], bank["b2"],
         block_slots, row_ids, block_b=block_b, meta_words=meta_words,
         with_actions=True, interpret=interpret_mode(),

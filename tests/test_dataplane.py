@@ -201,6 +201,72 @@ def test_runtime_fanout_parity(bank2):
         assert rt.completed_slots == base.completed_slots, (strategy, fanout)
 
 
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+def test_all_queue_launch_matches_per_queue_launches(bank2, backend):
+    """One launch over every queue's rows a tick gives, per queue, the
+    slots, verdicts and actions of one launch per queue and of the exact
+    ``take`` path, on ticks with empty queues, partial batches, a
+    ``FailQueues`` failover and a mid-run ``SwapSlot`` epoch."""
+    trace = small_trace(seed=3)
+    kw = dict(strategy="fused", backend=backend, ring_capacity=4096)
+    runs = {}
+    for name, over in [("all", {}), ("loop", {"fanout": "loop"}),
+                       ("take", {"strategy": "take"})]:
+        rt = DataplaneRuntime(bank2, **dict(kw, **over), num_queues=4,
+                              batch=32, record=True)
+        served = []
+        rt.on_retire = (lambda q, rows, s, v, a, t, served=served:
+                        served.append((t, q, rows[:, scenarios.SEQ_WORD],
+                                       s, v, a)))
+        play(rt, trace)
+        runs[name] = rt, served
+    all_rt, all_served = runs["all"]
+    assert all_rt.fanout == "vmap"
+    assert all_rt.telemetry.slot_swaps == 1
+    assert all_rt.failed_queues == {0}
+    by_tick = {}
+    for t, q, seq, *_ in all_served:
+        by_tick.setdefault(t, {})[q] = len(seq)
+    assert any(len(qs) < 4 for qs in by_tick.values())          # empty queue
+    assert any(n < 32 for qs in by_tick.values() for n in qs.values())
+    for name in ("loop", "take"):
+        rt, served = runs[name]
+        assert rt.completed_seq == all_rt.completed_seq, name
+        assert rt.completed_slots == all_rt.completed_slots, name
+        assert rt.completed_verdicts == all_rt.completed_verdicts, name
+        assert len(served) == len(all_served), name
+        for got, want in zip(served, all_served):
+            assert got[:2] == want[:2], name
+            for g, w in zip(got[2:], want[2:]):
+                np.testing.assert_array_equal(g, w, err_msg=name)
+
+
+def test_retire_taps_get_fresh_arrays_every_tick(bank2, rng):
+    """The taps keep references without copying, so no tick's arrays may
+    alias another tick's."""
+    rt = DataplaneRuntime(bank2, num_queues=2, batch=16, ring_capacity=256,
+                          record=True)
+    taps = []
+    rt.on_retire = lambda q, rows, s, v, a, t: taps.append((s, v, a))
+    for _ in range(3):
+        rows = pkt.make_packets(
+            rng.integers(0, 2, 24),
+            rng.integers(0, 2**32, (24, pkt.PAYLOAD_WORDS), dtype=np.uint32))
+        rt.dispatch(rows, queues=np.arange(24) % 2)
+        rt.tick()
+    assert len(taps) == 6
+    arrays = [a for tap in taps for a in tap]
+    assert len({id(a) for a in arrays}) == len(arrays)
+    for i, (s0, v0, a0) in enumerate(taps):
+        for s1, v1, a1 in taps[i + 2::2]:           # later ticks
+            for x in (s0, v0, a0):
+                for y in (s1, v1, a1):
+                    assert not np.shares_memory(x, y)
+    # what the taps hold still reads as what was retired
+    flat = [int(x) for q in range(2) for s, _, _ in taps[q::2] for x in s]
+    assert flat == [x for q in range(2) for x in rt.completed_slots[q]]
+
+
 def test_runtime_failover_drains_dead_queue(bank2):
     trace = small_trace(seed=1)
     rt = DataplaneRuntime(bank2, num_queues=4, strategy="take", batch=32,
@@ -235,7 +301,13 @@ def test_telemetry_snapshot(bank2):
     ticks = spans.tick_summary(rt.spans.snapshot())
     assert ticks["ticks"] >= max(q["ticks"] for q in busy)
     assert 0 < ticks["mean_us"] <= ticks["max_us"]
-    assert rt.spans.snapshot()["spans"]["dp.tick.launch"]["count"] == sum(
+    # one launch and one pull a non-empty tick, serving every non-empty
+    # queue batch of it
+    snap_spans = rt.spans.snapshot()
+    launches = snap_spans["spans"]["dp.tick.launch"]["count"]
+    assert launches == snap_spans["spans"]["dp.retire.d2h"]["count"]
+    assert max(q["ticks"] for q in busy) <= launches
+    assert snap_spans["counters"]["dp.queue_batches"] == sum(
         q["ticks"] for q in busy)
     for q in busy:
         assert q["latency_p50_us"] <= q["latency_p99_us"]
@@ -326,24 +398,35 @@ def test_emergency_phase_shapes():
 
 
 # ---------------------------------------------------------------------------
-# structural audit: one fused launch per queue-block
+# structural audit: one fused launch per queue-block, and per tick
 # ---------------------------------------------------------------------------
 
-def test_one_fused_launch_per_queue_block(bank2, rng):
+@pytest.mark.parametrize("num_queues", [None, 4])
+def test_one_fused_launch_per_queue_block(bank2, rng, num_queues):
+    """One queue's block (``packet_step``) and a whole tick of ``num_queues``
+    queue blocks (``packet_step_queues``) each trace to ONE Pallas launch
+    with no payload-sized scatter or gather."""
     common = pytest.importorskip("benchmarks.common")
     from repro.core import pipeline
 
+    q = num_queues or 1
     packets = pkt.make_packets(
-        np.arange(32) % 2,
-        rng.integers(0, 2**32, (32, pkt.PAYLOAD_WORDS), dtype=np.uint32))
+        np.arange(32 * q) % 2,
+        rng.integers(0, 2**32, (32 * q, pkt.PAYLOAD_WORDS), dtype=np.uint32))
+    kw = dict(num_slots=2, strategy="fused", backend="pallas", block_b=16)
 
     def queue_block_step(p):
-        return pipeline.packet_step(bank2, p, num_slots=2, strategy="fused",
-                                    backend="pallas", block_b=16)
+        return pipeline.packet_step(bank2, p, **kw)
+
+    def tick_step(p):
+        return pipeline.packet_step_queues(bank2, p, **kw)
 
     import jax.numpy as jnp
+    if num_queues is None:
+        step, x = queue_block_step, jnp.asarray(packets)
+    else:
+        step, x = tick_step, jnp.asarray(packets.reshape(q, 32, -1))
     stats = common.jaxpr_stats(
-        queue_block_step, jnp.asarray(packets),
-        payload_threshold=32 * pkt.PAYLOAD_WORDS * 4)
+        step, x, payload_threshold=32 * pkt.PAYLOAD_WORDS * 4)
     assert stats["kernel_launches"] == 1
     assert stats["payload_roundtrip_bytes"] == 0

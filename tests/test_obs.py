@@ -561,8 +561,14 @@ def test_host_spans_counters_add_up():
     assert rec.snapshot()["counters"] == {}
 
 
-def test_runtime_tick_spans_once_per_nonempty_queue(bank2):
-    rt = DataplaneRuntime(bank2, num_queues=4, batch=32, ring_capacity=256)
+@pytest.mark.parametrize("fanout", ["auto", "loop"])
+def test_runtime_tick_spans_once_per_nonempty_queue(bank2, fanout):
+    """The per-queue retire spans open once per non-empty queue; the
+    copy, launch and pull once per tick (``auto``: one launch over every
+    queue's rows) or once per non-empty queue (``loop``)."""
+    from repro.core import bank as bank_lib
+    rt = DataplaneRuntime(bank2, num_queues=4, batch=32, ring_capacity=256,
+                          fanout=fanout)
     rt.on_retire = lambda *a: None
     rt.spans.enable()
     rng = np.random.default_rng(0)
@@ -571,18 +577,24 @@ def test_runtime_tick_spans_once_per_nonempty_queue(bank2):
     assert rt.tick() == len(queues)
     snap = rt.spans.snapshot()
     counts = {k: v["count"] for k, v in snap["spans"].items()}
-    per_queue = ("dp.tick.pad", "dp.tick.h2d", "dp.tick.launch",
-                 "dp.retire.d2h", "dp.retire.tap", "dp.retire.telemetry")
+    launches = 1 if fanout == "auto" else 3
+    per_launch = ("dp.tick.h2d", "dp.tick.launch", "dp.retire.d2h")
+    assert {k: counts[k] for k in per_launch} == dict.fromkeys(
+        per_launch, launches)
+    per_queue = ("dp.retire.tap", "dp.retire.telemetry")
     assert {k: counts[k] for k in per_queue} == dict.fromkeys(per_queue, 3)
     for name in ("dp.dispatch", "dp.tick", "dp.tick.control", "dp.tick.pop",
-                 "dp.retire.wait"):
+                 "dp.tick.pad", "dp.retire.wait"):
         assert counts[name] == 1, name
     assert "dp.retire.audit" not in counts       # audit is off
     assert "dp.dispatch.hash" not in counts      # queue ids were given
     assert counts["dp.dispatch.push"] == 1
     assert snap["counters"]["dp.rows_popped"] == len(queues)
     assert snap["counters"]["dp.ring_wait_ns"] > 0
-    assert snap["counters"]["dp.kernel_rows"] == 3 * rt._kernel_rows
+    assert snap["counters"]["dp.queue_batches"] == 3
+    rows_per_launch = 4 * 32 if fanout == "auto" else 32
+    assert snap["counters"]["dp.kernel_rows"] == launches * (
+        bank_lib.padded_rows(rows_per_launch, 2, 32))
     # every span of the tick ran inside it
     (tick,) = snap["slowest_ticks"]
     assert set(tick["self_ns"]) == {k for k in counts
@@ -590,20 +602,26 @@ def test_runtime_tick_spans_once_per_nonempty_queue(bank2):
                                                      "dp.retire."))}
 
 
+@pytest.mark.parametrize("num_queues", [1, 4])
 @pytest.mark.parametrize("num_slots", [1, 16])
-def test_kernel_rows_counter_is_the_padded_grouping(num_slots):
+def test_kernel_rows_counter_is_the_padded_grouping(num_slots, num_queues):
     import jax.numpy as jnp
     from repro.core import bank as bank_lib
     bank = executor.init_bank(jax.random.PRNGKey(1), num_slots)
-    rt = DataplaneRuntime(bank, num_queues=1, batch=128, block_b=32)
+    rt = DataplaneRuntime(bank, num_queues=num_queues, batch=128, block_b=32)
     rt.spans.enable()
     rng = np.random.default_rng(num_slots)
-    rt.dispatch(_packets(rng, 128, num_slots), queues=np.zeros(128, int))
+    n = 128 * num_queues
+    rt.dispatch(_packets(rng, n, num_slots),
+                queues=np.arange(n) % num_queues)
     rt.tick()
     b_pad = bank_lib.group_by_slot_padded(
-        jnp.zeros(128, jnp.int32), num_slots, 32).b_pad
-    assert b_pad == {1: 160, 16: 640}[num_slots]
-    assert rt.spans.snapshot()["counters"]["dp.kernel_rows"] == b_pad
+        jnp.zeros(n, jnp.int32), num_slots, 32).b_pad
+    assert b_pad == {(1, 1): 160, (16, 1): 640,
+                     (1, 4): 544, (16, 4): 1024}[num_slots, num_queues]
+    counters = rt.spans.snapshot()["counters"]
+    assert counters["dp.kernel_rows"] == b_pad
+    assert counters["dp.queue_batches"] == num_queues
 
 
 def test_host_spans_reach_the_profiler_trace(bank2, tmp_path):

@@ -99,3 +99,36 @@ def test_bnn_xnor_compiles(one_chip):
     _assert_kernel_compiles(
         bnn_xnor.xnor_matmul, _spec(one_chip, (256, W), jnp.uint32),
         _spec(one_chip, (H, W), jnp.uint32))
+
+
+
+@pytest.mark.parametrize("num_slots", [1, K])
+def test_served_tick_step_compiles(one_chip, monkeypatch, num_slots):
+    """The tick's one launch (``pipeline.packet_step_queues``: 4 queues of
+    128 packet rows, block_b 32) compiles as a module whose name holds
+    ``jit_packet_step``, around the kernel op ``%fused_forward.<n>``, and
+    returns the (4, 3, 128) int32 packed result."""
+    import re
+    from repro.core import pipeline
+    from repro.kernels import ops
+    # the step asks the default backend (the CPU here) whether to
+    # interpret its kernel: steer it to the chip's compiled kernel, and
+    # keep interpret-mode traces of other tests out of the jit caches
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    jax.clear_caches()
+    w1, b1, w2, b2 = (_spec(one_chip, (num_slots,) + s.shape[1:], s.dtype)
+                      for s in _bank_specs(one_chip))
+    try:
+        compiled = pipeline.packet_step_queues.lower(
+            {"w1p": w1, "b1": b1, "w2": w2, "b2": b2},
+            _spec(one_chip, (4, 128, META + W), jnp.uint32),
+            num_slots=num_slots, strategy="fused", backend="pallas",
+            block_b=32).compile()
+    finally:
+        jax.clear_caches()
+    hlo = compiled.as_text()
+    assert re.search(r"^HloModule jit_packet_step_queues\b", hlo, re.M)
+    assert re.search(r"%fused_forward(\.\d+)? = .*"
+                     r'custom_call_target="tpu_custom_call"', hlo)
+    out = compiled.out_info
+    assert out.shape == (4, 3, 128) and out.dtype == jnp.int32
