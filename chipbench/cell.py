@@ -99,12 +99,26 @@ def setup_jax() -> None:
 
 
 def devices(chips: int, rehearse: bool):
+    """Every device JAX finds; the cell runs on the first ``chips``.  A
+    cell on more than one chip needs exactly that many in sight: the
+    program's multi-chip path (``fanout="shard_map"``) spreads its queues
+    over every device it finds, and has no argument that names them."""
     import jax
     devs = jax.devices()
     if not rehearse and (devs[0].platform != "tpu" or len(devs) < chips):
         raise NoChip(f"cell needs {chips} TPU chip(s); JAX found "
                      f"{len(devs)} {devs[0].platform} device(s)")
+    if chips > 1 and len(devs) != chips:
+        raise NoChip(f"cell runs on {chips} chips and the program spreads "
+                     f"its queues over all it finds; JAX found {len(devs)}")
     return devs
+
+
+def placement(chips) -> dict:
+    """The runtime's arguments that put a cell on its chips: on more than
+    one, the queues split over them by ``shard_map``; on one, none, so the
+    program keeps its own default."""
+    return {"fanout": "shard_map"} if len(chips) > 1 else {}
 
 
 def _served_models(ticks, seqs, source, epochs, num_slots) -> np.ndarray:
@@ -139,6 +153,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     cfg = config(spec, wl["config"])
     mix = dict(traffic(wl["traffic"]), **(mix_overrides or {}))
     devs = devices(wl["chips"], rehearse)
+    chips = devs[:wl["chips"]]
     if os.path.join(ROOT, "src") not in sys.path:
         sys.path.insert(0, os.path.join(ROOT, "src"))
     from repro.dataplane import DataplaneRuntime
@@ -164,7 +179,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
                           monitor_share=mix["monitor_share"], seed=seed,
                           rss_buckets=cfg["rss_buckets"])
     marks.append(("inputs", clock()))
-    kw = {"backend": "pallas"} if interpret else {}
+    kw = dict(placement(chips), **({"backend": "pallas"} if interpret else {}))
     rt = DataplaneRuntime(bank, num_queues=cfg["queues"],
                           ring_capacity=cfg["ring_capacity"], **kw)
     h = harness_lib.Harness(rt, annotate=trace)
@@ -195,12 +210,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     trace_s = None
     if trace:
         jax.profiler.stop_trace()
-        reduced = tracing.reduce(tracing.find(TRACE_DIR))
+        reduced = tracing.reduce(tracing.find(TRACE_DIR),
+                                 device_ids=[d.id for d in chips])
         trace_s = clock() - t_drained
     compiles = events.between(t0, t_drained)
     gc_pauses = full_gc.between(t0, t_drained)
 
-    mem = [d.memory_stats() for d in devs[:wl["chips"]]]
+    mem = [d.memory_stats() for d in chips]
     peak_bytes = max((m or {}).get("peak_bytes_in_use", 0) for m in mem) \
         if any(mem) else None
     ids = {e.epoch_id for e in h.epochs}
@@ -251,7 +267,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     correct = (readings["checked"] > 0 and all(
         readings[k] <= lim for k, lim in check_lib.LIMITS.items()))
 
-    dev = devs[0]
+    dev = chips[0]
     peaks = None if rehearse else peaks_lib.for_kind(dev.device_kind)
     ctx = types.SimpleNamespace(
         cfg=cfg, traffic=mix, workload=wl, setup_s=setup_s, window=(t0, t1),
@@ -276,7 +292,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         "failed": int(np.unique(dropped).shape[0]),
         "metrics": values,
         "device": {"platform": dev.platform, "kind": dev.device_kind,
-                   "count": len(devs), "memory_peak_bytes": peak_bytes},
+                   "count": len(chips), "visible": len(devs),
+                   "memory_peak_bytes": peak_bytes},
     }
     if reduced is not None:
         result["device"]["busy_s"] = reduced.busy_s()
@@ -291,6 +308,6 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
             "e2e": ctx.e2e,
             "window": (t0, t1),
             "setup_parts": setup_parts,
-            "backlog": backlog, "aligned": (
+            "backlog": backlog, "chips": [d.id for d in chips], "aligned": (
                 None if reduced is None
-                else all(d.aligned for d in reduced.devices))}
+                else {d.name: d.aligned for d in reduced.devices})}

@@ -93,6 +93,7 @@ class Harness:
         self.annotate = annotate
         self.ticks = 0
         self.recording = False
+        self.timing = False
         self.spans: dict[str, list] = {}
         self.epochs: list[Epoch] = []
         self._unapplied: list[Epoch] = []
@@ -109,6 +110,7 @@ class Harness:
     def start(self) -> None:
         """Begin the window's records (spans, taps, epochs)."""
         self.spans = {}
+        self.timing = True
         self.retired, self.dropped, self.late = [], [], []
         self.epochs, self.submit_us = [], []
         self.offered = 0
@@ -137,7 +139,7 @@ class Harness:
             dt = clock() - t0
             if ann is not None:
                 ann.__exit__(None, None, None)
-            if self.recording:
+            if self.timing:
                 s = self.spans.setdefault(name, [0.0, 0])
                 s[0] += dt
                 s[1] += 1
@@ -202,7 +204,10 @@ class Harness:
             self.rt.retire_all()
 
     def drain(self) -> None:
-        """Tick until the rings are empty, then retire what is in flight."""
+        """Tick until the rings are empty, then retire what is in flight.
+        The window's spans end here; its taps go on recording, so that
+        every packet it offered is checked."""
+        self.timing = False
         while self.waiting() or self._unapplied:
             self.tick()
         self.flush()

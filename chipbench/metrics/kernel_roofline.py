@@ -1,7 +1,11 @@
 """kernel_roofline: the least time the chip needs for the window's ticks
 (``work.least_time``: ops at the int8 peak or bytes at the HBM bandwidth,
 whichever is longer, tick by tick) over the fused kernel's device time in
-the traced window, in %."""
+the traced window, in %.
+
+On N chips the kernel's time is summed over them, so this is the share of
+the N chips' combined roof, ``(least / N) / (spent / N)``, while the
+program splits the tick's work evenly over them."""
 
 from chipbench.metrics import kernel_ns_per_pkt
 

@@ -216,6 +216,9 @@ def main(argv=None) -> int:
     if args.interpret and not args.rehearse:
         ap.error("--interpret needs --rehearse")
 
+    if args.rehearse:
+        from chipbench.run import virtual_chips
+        virtual_chips(args.workload)
     import jax
     from chipbench import cell, harness as harness_lib
     cell.setup_jax()
@@ -243,7 +246,7 @@ def main(argv=None) -> int:
     if args.trace:
         path = tracing.find(cell.TRACE_DIR)
         report["idle_by_program_span"] = idle_by_program_span(
-            tracing.reduce(path), host_events(path))
+            tracing.reduce(path, device_ids=out["chips"]), host_events(path))
     print(json.dumps(report), flush=True)
     return 0
 

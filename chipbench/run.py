@@ -9,9 +9,11 @@ beside their limits, and as its last line of standard output one JSON
 object: ``correct``, ``attempted`` (timed packets offered), ``failed``
 (ring-edge drops), ``metrics`` (the cell's end-to-end metrics, or with
 ``--trace 1`` its per-layer metrics), ``device``, ``breakdown`` (traced
-runs) and ``check``.  Off a TPU, or with fewer chips than the cell asks
-for, it prints no result and exits 2.  ``--rehearse`` runs on whatever
-JAX finds (the CPU, with ``JAX_PLATFORMS=cpu``) and prints no metric.
+runs) and ``check``.  Off a TPU, with fewer chips than the cell asks
+for, or, for a cell on several chips, with any other number, it prints
+no result and exits 2.  ``--rehearse`` runs on whatever JAX finds (the
+CPU, with ``JAX_PLATFORMS=cpu``; as many devices as a cell on several
+chips asks for) and prints no metric.
 """
 
 from __future__ import annotations
@@ -28,6 +30,19 @@ import sys  # noqa: E402
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
+def virtual_chips(name: str) -> None:
+    """Give a rehearsal of a cell on N > 1 chips N devices of the CPU;
+    XLA reads the flag when JAX first starts a backend."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        chips = next((w["chips"] for w in json.load(f)["workloads"]
+                      if w["name"] == name), 1)
+    if chips > 1:
+        os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+            os.environ.get("XLA_FLAGS"),
+            f"--xla_force_host_platform_device_count={chips}")))
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
@@ -42,6 +57,8 @@ def main(argv=None) -> int:
     if args.interpret and not args.rehearse:
         ap.error("--interpret needs --rehearse")
 
+    if args.rehearse:
+        virtual_chips(args.workload)
     try:
         from chipbench import cell
     except ImportError as e:
@@ -73,7 +90,8 @@ def main(argv=None) -> int:
                                out["setup_parts"].items()), file=sys.stderr)
     if out["trace_s"] is not None:
         print(f"trace: stopped and reduced in {out['trace_s']:.2f} s; "
-              f"device clock aligned to the host's: {out['aligned']}",
+              "each chip's clock aligned to the host's: "
+              + ", ".join(f"{k} {v}" for k, v in out["aligned"].items()),
               file=sys.stderr)
     gcp = out["gc_pauses"]
     print(f"gc: {len(gcp)} full collections in the window"

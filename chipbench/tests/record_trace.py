@@ -1,12 +1,17 @@
 """Record the small device trace that ``test_trace_reduce.py`` reads.
 
     python chipbench/tests/record_trace.py --out out/trace_sample
+    python chipbench/tests/record_trace.py --out out/trace_sample \
+        --chips 4 --queues 16 --ticks 4
 
 Serves a few ticks of the ``h32-k16-q4.saturate`` cell on one TPU through
 the harness, with its host spans and the ``window`` span as
 ``TraceAnnotation``s and the python tracer off, as a traced run has them,
 and writes the ``.xplane.pb`` and a summary of what the reduction reads
-from it.  The kept copy is ``chipbench/tests/data/tick_trace.xplane.pb``.
+from it.  With ``--chips 4 --queues 16`` the same configuration's 16
+queues are spread over four chips, as the harness runs a cell on four.
+The kept copies are ``chipbench/tests/data/tick_trace.xplane.pb`` and
+``tick_trace_4chips.xplane.pb``.
 """
 
 from __future__ import annotations
@@ -34,6 +39,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", required=True)
     ap.add_argument("--ticks", type=int, default=8)
+    ap.add_argument("--chips", type=int, default=1)
+    ap.add_argument("--queues", type=int, default=None,
+                    help="queues in place of the configuration's")
     args = ap.parse_args(argv)
 
     import jax
@@ -41,15 +49,17 @@ def main(argv=None) -> int:
     from chipbench.traffic import closed
     from chipbench.traffic.packets import PacketSource
     cell.setup_jax()
-    devs = cell.devices(1, rehearse=False)
+    devs = cell.devices(args.chips, rehearse=False)
     sys.path.insert(0, os.path.join(ROOT, "src"))
     from repro.dataplane import DataplaneRuntime
 
     spec = cell.load_spec()
-    cfg = cell.config(spec, "h32-k16-q4")
+    cfg = dict(cell.config(spec, "h32-k16-q4"))
+    cfg["queues"] = args.queues or cfg["queues"]
     mix = cell.traffic("saturate")
     rt = DataplaneRuntime(weights.bank(cfg, 7), num_queues=cfg["queues"],
-                          ring_capacity=cfg["ring_capacity"])
+                          ring_capacity=cfg["ring_capacity"],
+                          **cell.placement(devs[:args.chips]))
     h = harness.Harness(rt, annotate=True)
     src = PacketSource(slots=cfg["slots"], flows=mix["flows"],
                        monitor_share=mix["monitor_share"], seed=7)
@@ -69,14 +79,16 @@ def main(argv=None) -> int:
     h.drain()
     jax.profiler.stop_trace()
     path = tracing.find(raw)
+    name = "tick_trace" + (f"_{args.chips}chips" if args.chips > 1 else "")
     with open(path, "rb") as f, \
-            open(os.path.join(args.out, "tick_trace.xplane.pb"), "wb") as g:
+            open(os.path.join(args.out, f"{name}.xplane.pb"), "wb") as g:
         g.write(scrub(f.read()))
-    r = tracing.reduce(path)
+    r = tracing.reduce(path, device_ids=[d.id for d in devs[:args.chips]])
     from chipbench.metrics import kernel_ns_per_pkt
     print(json.dumps({
         "device": devs[0].device_kind, "bytes": os.path.getsize(path),
         "window_s": r.window_s, "busy_s": r.busy_s(),
+        "planes": [d.name for d in r.devices],
         "aligned": [d.aligned for d in r.devices],
         "modules": [len(d.modules) for d in r.devices],
         "ops": [len(d.ops) for d in r.devices],

@@ -106,7 +106,8 @@ def test_rehearsal_prints_the_program_spans():
     assert out["result"]["correct"] is True
     prog = out["program"]
     assert set(program_spans.METRICS) <= set(prog["metrics"])
-    assert prog["metrics"]["kernel_rows_per_pkt.tput"] == 5.0
+    # one launch a tick: 512 rows of 16 slots pad to 1,024 kernel rows
+    assert prog["metrics"]["kernel_rows_per_pkt.tput"] == 2.0
     assert 0.5 < prog["tick_split"]["over_harness_tick"] <= 1.0
     assert prog["slowest_ticks"] and "dp.tick" in prog["slowest_ticks"][0][
         "self_us"]

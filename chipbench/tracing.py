@@ -9,13 +9,24 @@ What a TPU v5e trace holds (read by hand from one recorded on the chip,
   ``custom_call_target="tpu_custom_call"`` (named ``%fused_forward.<n>``);
 * plane ``/host:CPU``: one line per host thread; the harness's spans
   (``jax.profiler.TraceAnnotation``) sit on the ``python`` line, and the
-  runtime marks each program launch with ``tpu::System::Execute``.
+  runtime marks each program launch with ``tpu::System::Execute``, whose
+  ``core_id`` stat is the chip it runs on.
 
-The device's timestamps run about a millisecond off the host's.  Programs
-run in the order they are launched, so the device clock is moved to the
-host's by the least shift that starts no program before its launch:
-``max(launch_start - module_start)`` over launches and modules paired in
-order.  Where the counts differ, no shift is made and ``aligned`` is False.
+On four chips (``tests/data/tick_trace_4chips.xplane.pb``, 16 queues by
+``shard_map``) one launch of the sharded step is four
+``tpu::System::Execute`` events, one per chip, each on a
+``py_xla_execute`` thread of its own with that chip's ``core_id``, and
+four ``XLA Modules`` events, one on each chip's plane.  Copying the
+tick's batch from chip 0 into the four shards adds a ``jit__multi_slice``
+module on ``/device:TPU:0`` alone, launched from the ``main`` thread with
+``core_id`` 0.  So each chip has one launch for each of its modules.
+
+Each chip's timestamps run about a millisecond off the host's, by its own
+amount.  A chip runs programs in the order they are launched to it, so
+its clock is moved to the host's by the least shift that starts none of
+its programs before its launch: ``max(launch_start - module_start)`` over
+that chip's launches and modules paired in order.  Where their counts
+differ, no shift is made and that chip's ``aligned`` is False.
 """
 
 from __future__ import annotations
@@ -31,6 +42,7 @@ WINDOW = "window"
 LAUNCH = "tpu::System::Execute"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+DEVICE_PLANE = "/device:TPU:"
 
 
 @dataclasses.dataclass
@@ -152,35 +164,41 @@ def _events(line):
             for e in line.events]
 
 
-def reduce(path: str) -> Reduced:
-    """Read one trace file into a ``Reduced``."""
+def reduce(path: str, device_ids=None) -> Reduced:
+    """Read one trace file into a ``Reduced``: the planes of the chips
+    ``device_ids`` names (``jax.Device.id``), or every chip's where None."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    spans, launches, window = [], [], None
+    spans, window = [], None
+    launches = collections.defaultdict(list)   # chip -> launch starts
     for plane in pd.planes:
         if plane.name != "/host:CPU":
             continue
         for line in plane.lines:
-            for s, e, name in _events(line):
+            for ev in line.events:
+                s, e, name = ev.start_ns, ev.start_ns + ev.duration_ns, ev.name
                 if name in SPANS:
                     spans.append((s, e, name))
                 elif name == WINDOW:
                     window = (s, e)
                 elif name == LAUNCH:
-                    launches.append(s)
+                    launches[dict(ev.stats).get("core_id")].append(s)
     if window is None:
         raise ValueError(f"trace {path} has no '{WINDOW}' span")
-    launches.sort()
     devices = []
     for plane in pd.planes:
-        if not plane.name.startswith("/device:TPU:"):
+        if not plane.name.startswith(DEVICE_PLANE):
+            continue
+        chip = int(plane.name[len(DEVICE_PLANE):])
+        if device_ids is not None and chip not in device_ids:
             continue
         lines = {line.name: _events(line) for line in plane.lines}
         ops = lines.get(OPS_LINE, [])
         mods = sorted(lines.get(MODULES_LINE, []))
+        mine = sorted(launches.get(chip, ()))
         shift, aligned = 0, False
-        if mods and len(mods) == len(launches):
-            shift = max(l - m[0] for l, m in zip(launches, mods))
+        if mods and len(mods) == len(mine):
+            shift = max(l - m[0] for l, m in zip(mine, mods))
             aligned = True
         devices.append(Device(
             name=plane.name,

@@ -10,6 +10,11 @@ an MXU +-1 matmul or something deduplicated.
   slot's weights read once, and each packet's score and action written
   (8 B).  A tick is the unit the algorithm serves at once, so the count
   does not change with how many launches the program splits it into.
+* Chips: ``least_time`` is one chip's.  A tick split over N chips, each
+  reading its own packets and the weights of the slots they use, has at
+  best a least time of ``least_time / N`` (its weights read once, not on
+  every chip), which is the roof ``kernel_roofline`` divides by once it
+  sums the kernel's time over the N chips.
 """
 
 from __future__ import annotations
