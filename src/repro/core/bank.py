@@ -214,8 +214,13 @@ def pad_group_by_slot(
 # double-buffered bank: zero-copy SwapSlot commit (DESIGN.md §14)
 # ---------------------------------------------------------------------------
 
-def copy_bank(bank: Params) -> Params:
-    """Deep device copy of a bank pytree (fresh buffers, same contents)."""
+def copy_bank(bank: Params, sharding=None) -> Params:
+    """Deep device copy of a bank pytree (fresh buffers, same contents):
+    where it is, or placed with ``sharding`` (e.g. replicated on every
+    chip of a mesh).  The copy comes after the put, which may keep a
+    source buffer for the shard on its own device."""
+    if sharding is not None:
+        bank = jax.device_put(bank, sharding)
     return jax.tree_util.tree_map(lambda leaf: jnp.asarray(leaf).copy(), bank)
 
 
@@ -274,12 +279,22 @@ class DoubleBufferedBank:
         other; ``stage`` resyncs the shadow's dirty slots from the active
         buffer before writing new params, so a flip always publishes a
         complete bank.
+      * with ``sharding`` both buffers, every staged slot and every
+        copy-on-write or reseeded buffer are placed with it (the
+        ``shard_map`` fan-out keeps the bank replicated on each chip of
+        its mesh), so a flip or a resync never moves the bank between
+        devices.  ``puts`` counts the placements of bank bytes onto the
+        device(s) (``on_put`` is called at each): two at construction,
+        one per staged slot, copy-on-write copy or reseed.
     """
 
-    def __init__(self, bank: Params):
+    def __init__(self, bank: Params, *, sharding=None, on_put=None):
         self.num_slots = bank_size(bank)
+        self.sharding = sharding
+        self._on_put = on_put
+        self.puts = 0
         # private copies: donation must never invalidate the caller's arrays
-        self._bufs = [_Buf(copy_bank(bank)), _Buf(copy_bank(bank))]
+        self._bufs = [_Buf(self._copy(bank)), _Buf(self._copy(bank))]
         self._active = 0
         self._dirty: list[set[int]] = [set(), set()]
         self._staged: dict[Any, tuple[int, Params]] = {}
@@ -287,6 +302,23 @@ class DoubleBufferedBank:
         self._committed: dict[Any, int] = {}
         self.stages = self.syncs = self.flips = 0
         self.discards = self.unalias_copies = 0
+
+    def _counted_put(self) -> None:
+        self.puts += 1
+        if self._on_put is not None:
+            self._on_put()
+
+    def _copy(self, tree: Params) -> Params:
+        """A fresh copy of a whole bank where the bank lives."""
+        self._counted_put()
+        return copy_bank(tree, self.sharding)
+
+    def _place(self, params: Params) -> Params:
+        """One slot's params where the bank lives, for ``_stage_slot``."""
+        self._counted_put()
+        if self.sharding is not None:
+            return jax.device_put(params, self.sharding)
+        return jax.tree_util.tree_map(jnp.asarray, params)
 
     # -- views ------------------------------------------------------------
 
@@ -349,7 +381,7 @@ class DoubleBufferedBank:
         buf = self._bufs[sh]
         if buf.pins:
             # copy-on-write: the pinned buffer stays with its pinner
-            buf = self._bufs[sh] = _Buf(copy_bank(buf.tree))
+            buf = self._bufs[sh] = _Buf(self._copy(buf.tree))
             self.unalias_copies += 1
         act = self._bufs[self._active].tree
         for k in sorted(self._dirty[sh]):
@@ -358,9 +390,7 @@ class DoubleBufferedBank:
             buf.tree = _sync_slot(buf.tree, act, jnp.int32(k))
             self.syncs += 1
         self._dirty[sh].clear()
-        buf.tree = _stage_slot(
-            buf.tree, jax.tree_util.tree_map(jnp.asarray, params),
-            jnp.int32(slot))
+        buf.tree = _stage_slot(buf.tree, self._place(params), jnp.int32(slot))
         self._staged[token] = (slot, params)
         self._staged_epoch = epoch
         self.stages += 1
@@ -428,7 +458,7 @@ class DoubleBufferedBank:
         — possibly pinned — and marked fully dirty so the next stage
         resyncs it."""
         self.discard_staged()
-        self._bufs[self._active] = _Buf(copy_bank(bank))
+        self._bufs[self._active] = _Buf(self._copy(bank))
         self._dirty[self._active].clear()
         self._dirty[1 - self._active] = set(range(self.num_slots))
         self._committed.clear()

@@ -30,8 +30,14 @@ bit.  Fan-out modes (``fanout=``) only split that work:
 * ``vmap``      — the one flat launch on one device (``auto`` picks it
                   for every strategy).
 * ``shard_map`` — the same flat step as each shard's body, queues sharded
-                  over a device mesh (`repro.launch.mesh.make_queue_mesh`)
-                  exactly like RSS maps flows onto NIC queues.
+                  over a device mesh (`repro.launch.mesh.make_queue_mesh`,
+                  over exactly ``devices=`` when given) exactly like RSS
+                  maps flows onto NIC queues.  The bank is placed
+                  replicated on every chip of the mesh once, when the
+                  runtime is built (and each staged slot when it is
+                  staged), and each tick's batch goes straight into
+                  per-chip shards in one ``device_put``, so a launch
+                  moves no bank bytes and stages nothing on one chip.
                   Host-simulated on 1-device CPU CI; real spread on TPU.
 * ``loop``      — one launch and one pull per non-empty queue: kept only
                   as the per-queue reference the parity tests compare
@@ -54,8 +60,11 @@ each host step of the sequential engine: ``dp.dispatch`` (``.hash``,
 ``.push``), ``dp.tick`` (``.control``, ``.pop``, ``.pad``, then per
 launch ``.h2d`` and ``.launch``) and the retire (``dp.retire.wait``, per
 launch ``.d2h``, per queue ``.tap``, ``.telemetry``, ``.audit``), with
-the counters ``dp.rows_popped``, ``dp.ring_wait_ns``, ``dp.kernel_rows``
-and ``dp.queue_batches`` (non-empty queue batches served).
+the counters ``dp.rows_popped``, ``dp.ring_wait_ns``, ``dp.kernel_rows``,
+``dp.queue_batches`` (non-empty queue batches served), ``dp.h2d_bytes``
+(host-to-device bytes of the launches' batches, summed over the chips)
+and ``dp.bank_puts`` (placements of bank bytes onto the device(s): at
+construction, per staged slot, never per tick).
 """
 
 from __future__ import annotations
@@ -68,7 +77,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.control import (ControlPlane, FailQueues, ProgramReta,
                            RestoreQueues, SetPolicy, SwapSlot)
@@ -176,6 +185,10 @@ class _InFlight:
 class DataplaneRuntime:
     """Single-host multi-queue data-plane runtime (DESIGN.md §6/§7).
 
+    ``devices`` (``fanout="shard_map"`` only) names the devices the
+    queues are sharded over; their count must divide ``num_queues``.
+    Without it the mesh is `repro.launch.mesh.make_queue_mesh`'s default.
+
     Public surface: ``dispatch`` (arrival edge), ``tick`` (pipeline
     step), ``retire_all``/``drain`` (flush), ``control`` (the epoch-
     stamped mutation funnel, `repro.control.ControlPlane`),
@@ -212,19 +225,40 @@ class DataplaneRuntime:
         log_capacity: int | None = None,
         log_spill: str | None = None,
         double_buffer: bool = True,
+        devices=None,
     ):
-        self.bank = bank
         self.num_queues = int(num_queues)
         self.num_slots = int(num_slots if num_slots is not None
                              else bank_lib.bank_size(bank))
+        self.spans = HostSpans()
+        if fanout == "auto":
+            fanout = "vmap"
+        if fanout not in ("loop", "vmap", "shard_map"):
+            raise ValueError(f"unknown fanout {fanout!r}")
+        if devices is not None and fanout != "shard_map":
+            raise ValueError("devices= names a shard_map mesh; "
+                             f"fanout is {fanout!r}")
+        self.fanout = fanout
+        # Under shard_map the bank lives replicated on every chip of the
+        # queue mesh and each tick's batch is sharded over its queue axis
+        self._mesh = self._bank_sharding = self._batch_sharding = None
+        if fanout == "shard_map":
+            self._mesh, self._axis = mesh_lib.make_queue_mesh(
+                self.num_queues, devices)
+            self._bank_sharding = NamedSharding(self._mesh, P())
+            self._batch_sharding = NamedSharding(self._mesh, P(self._axis))
         # Double-buffered bank: the runtime owns two private device
         # copies; ``self.bank`` aliases the active one.  The caller's
         # ``bank`` argument is never donated.
         self._bankbuf = None
         self._epoch_nonce: object = None
         if double_buffer:
-            self._bankbuf = bank_lib.DoubleBufferedBank(bank)
+            self._bankbuf = bank_lib.DoubleBufferedBank(
+                bank, sharding=self._bank_sharding,
+                on_put=self._count_bank_put)
             self.bank = self._bankbuf.active
+        else:
+            self.bank = self._put_bank(bank)
         self.strategy = strategy
         self.batch = int(batch)
         self.block_b = min(int(block_b), self.batch)
@@ -251,7 +285,6 @@ class DataplaneRuntime:
             raise ValueError("pipeline_depth must be >= 1")
         self.pipeline_depth = int(pipeline_depth)
         self._inflight: collections.deque[_InFlight] = collections.deque()
-        self.spans = HostSpans()
         self._tick_count = 0
         self._faults = fault_injector
         self.control = ControlPlane(self, log_capacity=log_capacity,
@@ -259,11 +292,6 @@ class DataplaneRuntime:
         self.policy = policy          # initial config, not a mutation
         self.failed_queues: set[int] = set()
         self.bucket_load = np.zeros(len(self.reta), np.int64)
-        if fanout == "auto":
-            fanout = "vmap"
-        if fanout not in ("loop", "vmap", "shard_map"):
-            raise ValueError(f"unknown fanout {fanout!r}")
-        self.fanout = fanout
         self._step, self._kernel_rows = self._build_fanout(fanout)
         if megastep_ticks < 1:
             raise ValueError("megastep_ticks must be >= 1")
@@ -303,16 +331,29 @@ class DataplaneRuntime:
         if fanout == "loop":
             queues = 1
         elif fanout == "shard_map":
-            mesh, axis = queue_mesh(self.num_queues)
-            shards = mesh.shape[axis]
+            shards = self._mesh.shape[self._axis]
             step = jax.jit(jax.shard_map(
-                step, mesh=mesh,
-                in_specs=(P(), P(axis)), out_specs=P(axis), check_vma=False,
+                step, mesh=self._mesh, in_specs=(P(), P(self._axis)),
+                out_specs=P(self._axis), check_vma=False,
             ))
         rows = queues // shards * self.batch
         if self.strategy in _GROUPED_STRATEGIES:
             rows = bank_lib.padded_rows(rows, self.num_slots, self.block_b)
         return step, shards * rows
+
+    # -- bank placement -------------------------------------------------------
+
+    def _count_bank_put(self) -> None:
+        self.spans.count("dp.bank_puts", 1)
+
+    def _put_bank(self, tree):
+        """``tree`` (a bank or one slot's params) placed where the bank
+        lives: replicated on the mesh under ``shard_map``, else as given
+        (single-buffered bank only; the double buffer places its own)."""
+        if self._bank_sharding is None:
+            return tree
+        self._count_bank_put()
+        return jax.device_put(tree, self._bank_sharding)
 
     # -- control plane: command application (ControlPlane-only entry) -------
 
@@ -369,7 +410,7 @@ class DataplaneRuntime:
                                         force=True)
             else:
                 self.bank = bank_lib.update_slot(
-                    self.bank, cmd.slot, cmd.params)
+                    self.bank, cmd.slot, self._put_bank(cmd.params))
             self.telemetry.slot_swaps += 1
         elif isinstance(cmd, ProgramReta):
             self._install_reta(np.asarray(cmd.reta, np.int32))
@@ -460,7 +501,7 @@ class DataplaneRuntime:
             self._bankbuf.reseed(bank)
             self.bank = self._bankbuf.active
         else:
-            self.bank = bank
+            self.bank = self._put_bank(bank)
 
     def bank_pin(self):
         """Pin the current active bank buffer against donation (taken by
@@ -670,7 +711,13 @@ class DataplaneRuntime:
         launches = []
         for queues in groups:
             with sp.span("dp.tick.h2d"):
-                x = jnp.asarray(batch[queues[0]:queues[-1] + 1])
+                host = batch[queues[0]:queues[-1] + 1]
+                if self._batch_sharding is not None:
+                    # one put: each chip receives its own queues' rows
+                    x = jax.device_put(host, self._batch_sharding)
+                else:
+                    x = jnp.asarray(host)
+            sp.count("dp.h2d_bytes", host.nbytes)
             with sp.span("dp.tick.launch"):
                 launches.append((queues, self._step(self.bank, x)))
             sp.count("dp.kernel_rows", self._kernel_rows)

@@ -41,8 +41,10 @@ API_SURFACE = (
     ("repro.dataplane.mesh", "MeshDataplane"),
 )
 
+#: A repo path; the lookbehind keeps the tail of a longer path (the
+#: ``tests/...`` inside ``chipbench/tests/...``) from reading as one.
 _PATH_RE = re.compile(
-    r"\b((?:src/repro|benchmarks|tests|examples|docs)/[\w./-]*\w)")
+    r"(?<![\w/])((?:src/repro|benchmarks|tests|examples|docs)/[\w./-]*\w)")
 _MODULE_RE = re.compile(r"\brepro(?:\.[a-z_][a-z_0-9]*)+\b")
 _LINK_RE = re.compile(r"\[[^\]]+\]\(([^)\s]+)\)")
 _SECTION_RE = re.compile(r"§(\d+)")

@@ -13,18 +13,23 @@ from __future__ import annotations
 import math
 
 import jax
-from jax.sharding import AxisType
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 
-def _build(shape: tuple[int, ...], axes: tuple[str, ...]):
+def _build(shape: tuple[int, ...], axes: tuple[str, ...], devices=None):
     """The one funnel every mesh layout goes through.  Axes are ``Auto``:
     the compiler propagates shardings (``jax.make_mesh`` now defaults to
     ``Explicit`` axes, under which plain indexing of a sharded result,
-    such as one queue's slice of the fan-out output, is an error)."""
+    such as one queue's slice of the fan-out output, is an error).
+    ``devices`` lays the mesh over exactly those devices, in that order."""
     if len(shape) != len(axes):
         raise ValueError(f"mesh shape {shape} does not match axes {axes}")
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(AxisType.Auto,) * len(axes))
+    types = (AxisType.Auto,) * len(axes)
+    if devices is not None:
+        return Mesh(np.asarray(devices, dtype=object).reshape(shape),
+                    tuple(axes), axis_types=types)
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -41,14 +46,23 @@ def make_host_mesh(model_parallel: int = 1):
     return _build((n // model_parallel, model_parallel), ("data", "model"))
 
 
-def make_queue_mesh(num_queues: int):
+def make_queue_mesh(num_queues: int, devices=None):
     """A mesh whose leading axis shards the data-plane queue dimension.
 
-    Composes with ``make_host_mesh`` instead of re-deriving the layout:
-    the host mesh is reused whenever its data axis divides the queue
-    count; otherwise a dedicated 1-axis mesh is built over the largest
-    device count that does.  Returns ``(mesh, axis_name)``.
+    With ``devices`` the mesh is one ``queues`` axis over exactly those
+    devices, whose count must divide ``num_queues`` (``ValueError``
+    otherwise).  Without, it composes with ``make_host_mesh`` instead of
+    re-deriving the layout: the host mesh is reused whenever its data
+    axis divides the queue count; otherwise a dedicated 1-axis mesh is
+    built over the largest device count that does.  Returns
+    ``(mesh, axis_name)``.
     """
+    if devices is not None:
+        devices = list(devices)
+        if not devices or num_queues % len(devices):
+            raise ValueError(f"{len(devices)} devices cannot split "
+                             f"{num_queues} queues evenly")
+        return _build((len(devices),), ("queues",), devices), "queues"
     m = make_host_mesh(1)
     if num_queues % m.devices.shape[0] == 0:
         return m, "data"

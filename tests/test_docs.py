@@ -55,3 +55,16 @@ def test_design_has_section_14():
     assert re.search(r"^## §14 ", text, re.M)
     for phrase in ("pointer flip", "shadow", "LRU", "prefetch"):
         assert phrase in text
+
+
+def test_doclint_reads_whole_paths_only():
+    """A ``tests/...`` tail inside a longer path is not a path of its
+    own; a dead ``tests/...`` path still is."""
+    problems = []
+    doclint.check_paths(ROOT, "X.md", "see chipbench/tests/test_step_stall.py"
+                        " and `chipbench/tests/test_program_spans.py`",
+                        problems)
+    assert problems == []
+    doclint.check_paths(ROOT, "X.md", "see tests/test_no_such_file.py",
+                        problems)
+    assert problems == ["X.md: dead path 'tests/test_no_such_file.py'"]
