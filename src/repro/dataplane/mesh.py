@@ -208,8 +208,9 @@ class MeshDataplane:
         return self.shards[0].num_slots
 
     @property
-    def pipeline_depth(self) -> int:
-        """Bounded in-flight tick window (identical on every shard)."""
+    def pipeline_depth(self) -> int | None:
+        """Bounded in-flight tick window, None where each shard reads it
+        from its own rings (identical on every shard)."""
         return self.shards[0].pipeline_depth
 
     @property

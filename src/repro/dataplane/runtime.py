@@ -44,13 +44,19 @@ bit.  Fan-out modes (``fanout=``) only split that work:
                   the flat launch with.
 
 The tick loop is a 3-stage pipeline (dispatch / device / retire) with a
-bounded in-flight window of ``pipeline_depth`` ticks, the multi-queue
-form of ``switching.replay_trace(stream=True)``: each ``tick()`` pops at
-most ``batch`` rows per queue, pads to the static batch shape (no
+bounded in-flight window, the multi-queue form of
+``switching.replay_trace(stream=True)``: each ``tick()`` pops at most
+``batch`` rows per queue, pads to the static batch shape (no
 recompiles), issues the launch asynchronously, and retires the oldest
-tick once the window is full.  ``pipeline_depth=1`` degenerates to the
-synchronous loop; any depth produces bit-identical verdicts because
-every tick captures the bank/RETA version current at its dispatch.
+tick once the window is full.  By default (``pipeline_depth=None``) the
+window is read from the rings: a tick stays in flight only while some
+ring still holds a whole ``batch`` after its pop, so the next tick is
+already waiting; its result pull starts at launch and it retires behind
+the next tick's launch.  Otherwise it retires at once, so a lightly
+loaded stream waits no extra tick.  An integer ``pipeline_depth`` fixes
+the window at that many ticks; ``1`` is the synchronous loop.  Every
+window produces bit-identical verdicts because every tick captures the
+bank/RETA version current at its dispatch.
 ``audit=True`` re-scores every tick through the exact ``take`` path
 *against that captured bank* and counts verdict mismatches — valid
 across every control command kind, not just slot swaps.
@@ -61,6 +67,7 @@ each host step of the sequential engine: ``dp.dispatch`` (``.hash``,
 launch ``.h2d`` and ``.launch``) and the retire (``dp.retire.wait``, per
 launch ``.d2h``, per queue ``.tap``, ``.telemetry``, ``.audit``), with
 the counters ``dp.rows_popped``, ``dp.ring_wait_ns``, ``dp.kernel_rows``,
+``dp.retire.deferred`` (ticks retired behind a later tick's launch),
 ``dp.queue_batches`` (non-empty queue batches served), ``dp.h2d_bytes``
 (host-to-device bytes of the launches' batches, summed over the chips)
 and ``dp.bank_puts`` (placements of bank bytes onto the device(s): at
@@ -218,7 +225,7 @@ class DataplaneRuntime:
         rss_key: bytes = rss.DEFAULT_KEY,
         audit: bool = False,
         record: bool = False,
-        pipeline_depth: int = 1,
+        pipeline_depth: int | None = None,
         megastep_ticks: int = 1,
         policy=None,
         fault_injector=None,
@@ -281,9 +288,11 @@ class DataplaneRuntime:
         self.on_retire = None
         self.on_drop = None
         self._t_start: float | None = None
-        if pipeline_depth < 1:
+        if pipeline_depth is not None and pipeline_depth < 1:
             raise ValueError("pipeline_depth must be >= 1")
-        self.pipeline_depth = int(pipeline_depth)
+        # None: the in-flight window is read from the rings at each tick
+        self.pipeline_depth = (None if pipeline_depth is None
+                               else int(pipeline_depth))
         self._inflight: collections.deque[_InFlight] = collections.deque()
         self._tick_count = 0
         self._faults = fault_injector
@@ -667,10 +676,19 @@ class DataplaneRuntime:
             out[q, n:] = rows[n - 1] if n else 0
         return out
 
+    def _kept_in_flight(self) -> int:
+        """Ticks the window leaves in flight after a launch: a fixed
+        ``pipeline_depth - 1``, or, derived, 1 while some ring still
+        holds a whole batch (the next tick will launch at once) and 0
+        otherwise."""
+        if self.pipeline_depth is not None:
+            return self.pipeline_depth - 1
+        return int(any(len(r) >= self.batch for r in self.rings))
+
     def tick(self) -> int:
         """Pipeline stage 1 (dispatch): pop up to ``batch`` rows per queue
         and issue the workers asynchronously; stage 3 (retire) runs for
-        the oldest tick once more than ``pipeline_depth`` are in flight."""
+        every tick beyond the in-flight window (``_kept_in_flight``)."""
         with self.spans.span("dp.tick"):
             return self._tick()
 
@@ -702,6 +720,7 @@ class DataplaneRuntime:
             sp.count("dp.rows_popped", total)
             sp.count("dp.ring_wait_ns", round(
                 sum(float((t - ts).sum()) for _, ts in popped) * 1e9))
+        keep = self._kept_in_flight()
         with sp.span("dp.tick.pad"):
             batch = self._host_batch(popped)
         if self.fanout == "loop":
@@ -719,13 +738,21 @@ class DataplaneRuntime:
                     x = jnp.asarray(host)
             sp.count("dp.h2d_bytes", host.nbytes)
             with sp.span("dp.tick.launch"):
-                launches.append((queues, self._step(self.bank, x)))
+                out = self._step(self.bank, x)
+            if keep:
+                # retired behind a later launch: start its pull now
+                out.copy_to_host_async()
+            launches.append((queues, out))
             sp.count("dp.kernel_rows", self._kernel_rows)
         sp.count("dp.queue_batches", sum(1 for n in counts if n))
-        self._inflight.append(_InFlight(
-            self._tick_count, popped, counts, batch, launches, self.bank))
-        while len(self._inflight) > self.pipeline_depth - 1:
-            self._retire(self._inflight.popleft())
+        rec = _InFlight(
+            self._tick_count, popped, counts, batch, launches, self.bank)
+        self._inflight.append(rec)
+        while len(self._inflight) > keep:
+            old = self._inflight.popleft()
+            if old is not rec:
+                sp.count("dp.retire.deferred", 1)
+            self._retire(old)
         return total
 
     def _retire(self, rec: _InFlight) -> None:

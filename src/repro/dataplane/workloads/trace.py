@@ -457,7 +457,9 @@ def make_runtime(trace: WorkloadTrace, *, bank=None, audit: bool = False,
     kw = dict(strategy=meta.get("strategy", "fused"),
               batch=int(meta.get("batch", 128)),
               ring_capacity=int(meta.get("ring_capacity", 2048)),
-              pipeline_depth=int(meta.get("pipeline_depth", 1)),
+              # None (the window read from the rings) replays as None
+              pipeline_depth=(None if meta.get("pipeline_depth") is None
+                              else int(meta["pipeline_depth"])),
               policy=(make_policy(meta["policy"])
                       if meta.get("policy") else None),
               record=True, audit=audit)

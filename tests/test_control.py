@@ -17,6 +17,7 @@ from repro.core import executor, packet as pkt
 from repro.dataplane import (DataplaneRuntime, Phase, elephant_skew_phases,
                              emergency_phases, phase_commands, play, render,
                              rss, scenarios)
+from repro.dataplane.workloads.phases import SEQ_WORD
 
 
 @pytest.fixture(scope="module")
@@ -327,6 +328,123 @@ def test_pipeline_window_accounts_in_flight(bank2, rng):
     aud = rt.audit_conservation()
     assert aud["ok"] and aud["totals"]["in_flight"] == 0
     assert aud["totals"]["completed"] == 64
+
+
+# ---------------------------------------------------------------------------
+# the derived window (pipeline_depth=None): in flight only while backlogged
+# ---------------------------------------------------------------------------
+
+def _queued_rows(rng, per_queue, num_queues=2):
+    """``per_queue`` rows for each queue, with the queue ids to dispatch
+    them to (round robin, so every queue gets exactly ``per_queue``)."""
+    n = per_queue * num_queues
+    rows = pkt.make_packets(
+        rng.integers(0, 2, n),
+        rng.integers(0, 2**32, (n, pkt.PAYLOAD_WORDS), dtype=np.uint32))
+    rows[:, SEQ_WORD] = np.arange(n)
+    return rows, np.arange(n) % num_queues
+
+
+def test_derived_window_defers_a_backlogged_tick(bank2, rng):
+    """With a ring still holding a whole batch after the pop, ``tick()``
+    leaves its launch in flight and the next ``tick()`` retires it; the
+    last tick, which empties the rings, retires at once."""
+    rt = make_rt(bank2, num_queues=2, batch=16, record=True)
+    assert rt.pipeline_depth is None
+    rows, qs = _queued_rows(rng, 48)
+    rt.dispatch(rows, queues=qs)
+    done = lambda: sum(len(s) for s in rt.completed_seq)  # noqa: E731
+    rt.tick()
+    assert rt.in_flight_rows() == [16, 16] and done() == 0
+    assert rt.audit_conservation()["ok"]
+    rt.tick()
+    assert rt.in_flight_rows() == [16, 16] and done() == 32
+    rt.tick()
+    assert rt.in_flight_rows() == [0, 0] and done() == 96
+    aud = rt.audit_conservation()
+    assert aud["ok"] and aud["totals"]["completed"] == 96
+
+
+def test_derived_window_retires_at_once_below_one_batch(bank2, rng):
+    """With every ring below one batch after the pop, no tick stays in
+    flight: a lightly loaded stream waits no extra tick."""
+    rt = make_rt(bank2, num_queues=2, batch=16, record=True)
+    for per_queue in (8, 16, 3, 16):
+        rows, qs = _queued_rows(rng, per_queue)
+        rt.dispatch(rows, queues=qs)
+        rt.tick()
+        assert rt.in_flight_rows() == [0, 0]
+        assert sum(len(r) for r in rt.rings) == 0
+    assert rt.audit_conservation()["totals"]["completed"] == 2 * 43
+
+
+def test_derived_window_bit_identical_on_emergency(bank2):
+    """The derived window serves the per-queue seqs, verdicts and slots
+    of ``pipeline_depth=1`` on the emergency storyline, and engages
+    there (its backlogged ticks retire behind a later launch)."""
+    trace = render(emergency_phases(2), num_slots=2, seed=0)
+    runs = {}
+    for depth in (1, None):
+        rt = make_rt(bank2, batch=32, record=True, pipeline_depth=depth)
+        rt.spans.enable()
+        play(rt, trace)
+        aud = rt.audit_conservation()
+        assert aud["ok"] and aud["totals"]["completed"] == trace.total_packets
+        runs[depth] = (rt.completed_seq, rt.completed_verdicts,
+                       rt.completed_slots)
+        deferred = rt.spans.counters.get("dp.retire.deferred", 0)
+        assert (deferred > 0) == (depth is None)
+    assert runs[1] == runs[None]
+
+
+def test_epoch_during_a_deferred_tick_keeps_continuity(bank2, spare_params,
+                                                       rng):
+    """A swap submitted while a backlogged tick is in flight applies only
+    after that tick retires (against the bank it was dispatched with):
+    zero wrong verdicts, continuity holds, and the streams equal the
+    synchronous loop's."""
+    rows, qs = _queued_rows(rng, 64)
+    runs = {}
+    for depth in (1, None):
+        rt = make_rt(bank2, num_queues=2, batch=16, record=True, audit=True,
+                     strategy="fused", pipeline_depth=depth)
+        rt.dispatch(rows, queues=qs)
+        rt.tick()
+        if depth is None:
+            assert sum(rt.in_flight_rows()) == 32
+        rt.control.submit(SwapSlot(1, spare_params[0]))
+        rt.tick()
+        rt.control.submit(FailQueues((1,)), ProgramReta(
+            tuple(np.zeros(rss.RETA_SIZE, np.int64))))
+        rt.tick()
+        rt.drain()
+        cont = rt.control.continuity_audit()
+        assert cont["ok"], cont
+        assert all(e["wrong_verdict_in_window"] == 0 for e in cont["epochs"])
+        assert rt.telemetry.slot_swaps == 1
+        aud = rt.audit_conservation()
+        assert aud["ok"] and aud["wrong_verdict"] == 0
+        assert aud["totals"]["completed"] == 128
+        runs[depth] = (rt.completed_seq, rt.completed_verdicts,
+                       rt.completed_slots)
+    assert runs[1] == runs[None]
+
+
+def test_deferred_counter_counts_ticks_retired_behind_a_launch(bank2, rng):
+    """``dp.retire.deferred`` counts the ticks retired behind a later
+    launch: of five backlogged ticks the first four, not the last, which
+    empties the rings; the synchronous loop counts none."""
+    rows, qs = _queued_rows(rng, 80)
+    for depth, want in ((None, 4), (1, 0)):
+        rt = make_rt(bank2, num_queues=2, batch=16, pipeline_depth=depth)
+        rt.spans.enable()
+        rt.dispatch(rows, queues=qs)
+        for _ in range(5):
+            rt.tick()
+        c = rt.spans.counters
+        assert rt.spans.snapshot()["spans"]["dp.tick.launch"]["count"] == 5
+        assert c.get("dp.retire.deferred", 0) == want
+        assert sum(rt.in_flight_rows()) == 0
 
 
 # ---------------------------------------------------------------------------

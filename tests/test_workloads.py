@@ -112,6 +112,28 @@ def test_record_replay_bit_identical(bank2, tmp_path):
         (rt.telemetry.wrong_verdict, rt.telemetry.slot_swaps)
 
 
+@pytest.mark.parametrize("depth", [None, 3])
+def test_record_replay_keeps_pipeline_depth(bank2, tmp_path, depth):
+    """A trace replays at the window it was recorded with: the derived
+    window (``None``) as ``None``, a fixed depth as that depth."""
+    rendered = workloads.render(small_chaos_phases(), num_slots=2, seed=7,
+                                num_queues=3)
+    rt = _rt(bank2, pipeline_depth=depth)
+    rec = workloads.record(rt)
+    workloads.play(rec, rendered)
+    path = str(tmp_path / "depth.bswt")
+    workloads.save(rec.finish(name="depth", seed=7), path)
+    loaded = workloads.load(path)
+    assert loaded.meta["pipeline_depth"] == depth
+    rt2 = workloads.make_runtime(loaded)
+    assert rt2.pipeline_depth == depth
+    rep = workloads.replay(loaded, rt2)
+    assert rep["ok"], rep["mismatches"]
+    assert rep["digest_ok"] is True
+    assert rt2.completed_seq == rt.completed_seq
+    assert rt2.completed_verdicts == rt.completed_verdicts
+
+
 def test_record_replay_with_routing_policy(bank2, tmp_path):
     """Policy rebalance epochs are NOT in the recorded command timeline
     (they regenerate from the replaying runtime's own policy loop), so
