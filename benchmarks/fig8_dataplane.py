@@ -1,8 +1,8 @@
-"""Fig. 8 (repo extension) — multi-queue data-plane runtime scaling.
+"""Fig. 8 (repo extension) — multi-queue data-plane structural audits.
 
-Sweeps queue count x strategy over the emergency scenario (steady ->
-flash crowd -> link failover -> slot churn) and reports aggregate
-throughput per configuration, plus three hard structural audits:
+Runs the emergency scenario (steady -> flash crowd -> link failover ->
+slot churn) through the multi-queue runtime and checks three hard
+structural properties:
 
   * **one fused launch per queue-block** — the traced per-queue program
     (backend pinned to pallas) contains exactly ONE ``pallas_call``;
@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import os
 import sys
-import time
 
 if __package__ in (None, ""):  # invoked as `python benchmarks/fig8_dataplane.py`
     _root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -41,61 +40,9 @@ BATCH = 128
 BLOCK_B = 32
 
 
-#: Scan-window length the fused sweep (and the megastep sweep's center
-#: point) runs with — the ``--megastep-ticks`` default of the launch CLI.
-MEGASTEP_TICKS = 8
-
-
-def _run_scenario(bank, trace, num_queues: int, strategy: str,
-                  *, ring_capacity: int = 1024, audit: bool = False,
-                  megastep_ticks: int = 1, record: bool = False):
-    rt = DataplaneRuntime(
-        bank, num_queues=num_queues, strategy=strategy, batch=BATCH,
-        block_b=BLOCK_B, ring_capacity=ring_capacity, audit=audit,
-        megastep_ticks=megastep_ticks, record=record)
-    t0 = time.perf_counter()
-    reports = play(rt, trace)
-    dt = time.perf_counter() - t0
-    return rt, reports, dt
-
-
 def main():
     bank = executor.init_bank(jax.random.PRNGKey(0), NUM_SLOTS)
     trace = render(emergency_phases(NUM_SLOTS), num_slots=NUM_SLOTS, seed=0)
-
-    # -- queue-count x strategy throughput sweep --------------------------
-    # best-of-2: the first run compiles the jitted per-queue programs (the
-    # process-wide jit cache makes the second run warm), so the reported
-    # number is steady-state throughput, not compile time.  The fused
-    # strategy runs in deferred (megastep) mode — one compiled scan per
-    # 8-tick window (DESIGN.md §13); ``take`` stays on the sequential
-    # per-tick loop, so the pair also measures the megastep's win.
-    best_by = {}
-    for num_queues in (1, 2, 4):
-        for strategy in ("fused", "take"):
-            mt = MEGASTEP_TICKS if strategy == "fused" else 1
-            best = 0.0
-            # deferred mode gets a third rep: its first run compiles one
-            # scan variant per window shape, and single-core CI runners
-            # are noisy enough that one warm sample under-reports
-            for _ in range(3 if strategy == "fused" else 2):
-                rt, _, dt = _run_scenario(bank, trace, num_queues, strategy,
-                                          ring_capacity=8192,
-                                          megastep_ticks=mt)
-                aud = rt.audit_conservation()
-                assert aud["ok"], aud
-                done = aud["totals"]["completed"]
-                assert done == trace.total_packets, aud  # big rings: no drops
-                best = max(best, done / dt / 1e3)
-            best_by[(strategy, num_queues)] = best
-            reps = 3 if strategy == "fused" else 2
-            emit(f"fig8.{strategy}.q{num_queues}.kpps", best,
-                 f"{done} pkts {rt.fanout}-fanout best-of-{reps}")
-    losses = sum(best_by[("fused", q)] < best_by[("take", q)]
-                 for q in (1, 2, 4))
-    emit("fig8.audit.fused_beats_take", losses,
-         "expect=0 queue counts where fused < take")
-    assert losses == 0, best_by
 
     # -- structural audit: ONE fused launch per queue-block ---------------
     qpackets = jnp.asarray(pkt.make_packets(
@@ -122,9 +69,10 @@ def main():
     # small rings force real tail-drops during the flash crowd; audit mode
     # cross-checks every verdict against the exact path, including across
     # the online slot swap in the slot_churn phase.
-    rt, reports, _ = _run_scenario(bank, trace, 4, "fused",
-                                   ring_capacity=512, audit=True,
-                                   megastep_ticks=MEGASTEP_TICKS)
+    rt = DataplaneRuntime(
+        bank, num_queues=4, strategy="fused", batch=BATCH, block_b=BLOCK_B,
+        ring_capacity=512, audit=True)
+    reports = play(rt, trace)
     aud = rt.audit_conservation()
     assert aud["ok"], aud
     t = aud["totals"]
@@ -137,68 +85,6 @@ def main():
          "expect=0 across online slot swap")
     assert crowd["dropped"] > 0, crowd
     assert aud["wrong_verdict"] == 0, aud
-
-
-def _digest(rt):
-    """Order-sensitive digest of the per-queue completion streams."""
-    out = []
-    for q in range(rt.num_queues):
-        out.append((tuple(rt.completed_seq[q]),
-                    tuple(rt.completed_verdicts[q]),
-                    tuple(rt.completed_slots[q])))
-    return tuple(out)
-
-
-def megastep_main():
-    """Fig. 8m — megastep window-length sweep (BENCH_9.json).
-
-    Reports the fused strategy's throughput as a function of the scan
-    window (``--megastep-ticks``) at 4 queues, plus queue scaling at the
-    default window, and one structural audit: the deferred window must
-    reproduce the sequential per-tick loop's completion streams
-    (sequence ids, verdicts, slots — order-sensitive, per queue) exactly.
-    """
-    bank = executor.init_bank(jax.random.PRNGKey(0), NUM_SLOTS)
-    trace = render(emergency_phases(NUM_SLOTS), num_slots=NUM_SLOTS, seed=0)
-
-    for ticks in (1, 8, 64):
-        best = 0.0
-        for _ in range(3):
-            rt, _, dt = _run_scenario(bank, trace, 4, "fused",
-                                      ring_capacity=8192,
-                                      megastep_ticks=ticks)
-            done = rt.audit_conservation()["totals"]["completed"]
-            assert done == trace.total_packets
-            best = max(best, done / dt / 1e3)
-        emit(f"fig8m.fused.q4.t{ticks}.kpps", best,
-             f"scan window {ticks} best-of-3")
-    for num_queues in (1, 2):
-        best = 0.0
-        for _ in range(3):
-            rt, _, dt = _run_scenario(bank, trace, num_queues, "fused",
-                                      ring_capacity=8192,
-                                      megastep_ticks=MEGASTEP_TICKS)
-            done = rt.audit_conservation()["totals"]["completed"]
-            assert done == trace.total_packets
-            best = max(best, done / dt / 1e3)
-        emit(f"fig8m.fused.q{num_queues}.t{MEGASTEP_TICKS}.kpps", best,
-             f"scan window {MEGASTEP_TICKS} best-of-3")
-
-    # -- structural audit: megastep == sequential, bit for bit ------------
-    # same trace, same bank; the sequential run and the deferred run must
-    # agree on every completed packet's (seq, verdict, slot) in order.
-    rt_seq, _, _ = _run_scenario(bank, trace, 4, "fused",
-                                 ring_capacity=8192, record=True)
-    rt_meg, _, _ = _run_scenario(bank, trace, 4, "fused",
-                                 ring_capacity=8192, record=True,
-                                 audit=True, megastep_ticks=MEGASTEP_TICKS)
-    mismatch = int(_digest(rt_seq) != _digest(rt_meg))
-    emit("fig8m.audit.megastep_digest_mismatch", mismatch,
-         "expect=0 deferred window == sequential loop")
-    emit("fig8m.audit.wrong_verdict", rt_meg.telemetry.wrong_verdict,
-         "expect=0 suffix-dedup forward vs exact per-row path")
-    assert mismatch == 0
-    assert rt_meg.telemetry.wrong_verdict == 0
 
 
 if __name__ == "__main__":

@@ -163,7 +163,6 @@ def main(argv=None) -> None:
         ("fig6", fig6_slot_behavior.main),
         ("fig7", fig7_fused.main),
         ("fig8", fig8_dataplane.main),
-        ("fig8m", fig8_dataplane.megastep_main),
         ("fig9", fig9_control.main),
         ("fig10", fig10_mesh.main),
         ("fig11", fig11_workloads.main),

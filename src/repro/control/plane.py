@@ -60,7 +60,6 @@ import dataclasses
 import struct
 import time
 import zlib
-from typing import Any
 
 import msgpack
 
@@ -119,62 +118,6 @@ class EpochRecord:
             "host_ticks": (list(self.host_ticks)
                            if self.host_ticks is not None else None),
         }
-
-
-# -- device-delta serialization (megastep scan, DESIGN.md §13) --------------
-
-#: DeviceDelta.kind codes, matched by the megastep's in-scan applier.
-DELTA_SWAP = 1
-DELTA_RETA = 2
-
-
-@dataclasses.dataclass(frozen=True)
-class DeviceDelta:
-    """One control command pre-serialized for the on-device epoch queue.
-
-    The megastep runs N ticks inside one compiled ``lax.scan``; an epoch
-    that lands mid-window cannot call back into Python, so at apply time
-    each bank/RETA mutation is also *serialized* into the fixed-shape
-    form the scan body consumes: ``step`` is the scan step index the
-    delta precedes (deltas at step s are in effect for every row popped
-    at steps >= s, exactly the sequential tick-boundary semantics), and
-    within one step later queue entries overwrite earlier ones
-    (last-wins == submission order).  Rollback of a failed epoch simply
-    truncates the staged delta list back to its pre-epoch length — the
-    device never observes a rolled-back epoch.
-    """
-    step: int                    # scan step the delta applies before
-    kind: int                    # DELTA_SWAP | DELTA_RETA
-    slot: int = -1               # bank slot (DELTA_SWAP)
-    reta: Any = None             # (reta_size,) int32 (DELTA_RETA)
-    params: Any = None           # bank-slot pytree (DELTA_SWAP)
-
-
-def serialize_device_delta(cmd, *, step: int, runtime,
-                           reta_size: int) -> DeviceDelta | None:
-    """Serialize one *already applied* command into its device delta.
-
-    Called by the runtime's ``_apply_command`` in deferred (megastep)
-    mode, after the host mirror mutated: ``SwapSlot`` captures the new
-    slot params; every RETA-affecting command (``ProgramReta`` /
-    ``FailQueues`` / ``RestoreQueues``) captures the *resulting* host
-    table — the device carries a fixed ``reta_size`` mirror, so a
-    shorter/longer table is padded (with -1) or truncated.  Commands
-    with no device-visible state (``SetPolicy``) return None.
-    """
-    from repro.control.commands import (FailQueues, ProgramReta,
-                                        RestoreQueues)
-    import numpy as np
-    if isinstance(cmd, SwapSlot):
-        return DeviceDelta(step=step, kind=DELTA_SWAP, slot=int(cmd.slot),
-                           params=cmd.params)
-    if isinstance(cmd, (ProgramReta, FailQueues, RestoreQueues)):
-        table = np.asarray(runtime.reta, np.int32)
-        out = np.full(reta_size, -1, np.int32)
-        n = min(reta_size, table.shape[0])
-        out[:n] = table[:n]
-        return DeviceDelta(step=step, kind=DELTA_RETA, reta=out)
-    return None
 
 
 class ControlPlane:

@@ -245,11 +245,11 @@ def _sync_slot(shadow: Params, active: Params, slot) -> Params:
 class _Buf:
     """One of the two device-resident bank copies, with a pin count.
 
-    A pinned buffer is referenced outside the double buffer (an open
-    megastep window, an epoch snapshot held for rollback) and must never
-    be donated; ``DoubleBufferedBank.stage`` un-aliases it with a fresh
-    copy instead (copy-on-write — a lingering pin costs one extra copy,
-    never correctness)."""
+    A pinned buffer is referenced outside the double buffer (e.g. an
+    epoch snapshot held for rollback) and must never be donated;
+    ``DoubleBufferedBank.stage`` un-aliases it with a fresh copy instead
+    (copy-on-write — a lingering pin costs one extra copy, never
+    correctness)."""
 
     __slots__ = ("tree", "pins")
 

@@ -115,20 +115,11 @@ class MeshDataplane:
     batch, ring_capacity, audit, record, pipeline_depth, ...) pass
     through to every shard; ``policy`` is held at mesh level and sees
     the merged, global-id view.
-
-    ``megastep_ticks > 1`` puts every shard in deferred (megastep) mode:
-    each host runs its staged tick windows on device in one compiled
-    scan (DESIGN.md §13) *between* epoch barriers — the barrier's
-    ``retire_all`` is exactly the per-shard flush point, so a committing
-    epoch still observes every shard quiescent, and mesh-level fault
-    injection (leases, quorum, injected stalls) keeps per-tick host
-    control because it never reaches shard internals.
     """
 
     def __init__(self, bank, *, hosts: int, num_queues: int,
                  policy=None, fault_injector=None, lease_ticks: int = 8,
                  suspect_after: int = 2, quorum: int | None = None,
-                 megastep_ticks: int = 1,
                  log_capacity: int | None = None,
                  log_spill: str | None = None, **runtime_kw):
         if hosts < 1:
@@ -141,7 +132,7 @@ class MeshDataplane:
         # scope, over global ids — not per host over local ids
         self.shards = [
             DataplaneRuntime(bank, num_queues=self.num_queues_per_host,
-                             megastep_ticks=megastep_ticks, **runtime_kw)
+                             **runtime_kw)
             for _ in range(self.hosts)
         ]
         self.reta = rss.mesh_indirection_table(
@@ -612,7 +603,6 @@ class MeshDataplane:
                                if self._faults is not None else [])
         out["fanout"] = self.shards[0].fanout
         out["strategy"] = self.shards[0].strategy
-        out["engine"] = self.shards[0].engine
         out["backend"] = ops._resolve(self.shards[0].backend)
         out["pipeline_depth"] = self.pipeline_depth
         out["policy"] = getattr(self.policy, "name", None)

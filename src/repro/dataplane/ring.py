@@ -118,97 +118,3 @@ class PacketRing:
     def ok(self) -> bool:
         s = self.conservation()
         return bool(s["producer_ok"] and s["consumer_ok"])
-
-
-# ---------------------------------------------------------------------------
-# Device-resident rings (the megastep's fast-path mirror, DESIGN.md §13)
-# ---------------------------------------------------------------------------
-#
-# Pure-jnp ring ops over a flat pytree so the whole multi-queue ring state
-# can live on device and evolve inside one compiled ``lax.scan``:
-#
-#     {"buf":  (Q * capacity, words) uint32,   flattened queue-major
-#      "head": (Q,) int32,  "size": (Q,) int32}
-#
-# Semantics are bit-identical to ``PacketRing``: FIFO within a queue,
-# burst-prefix admission, tail drop when full.  The host ``PacketRing``
-# mirror stays authoritative for counters/timestamps; these ops only have
-# to reproduce the *row content and order* the host mirror predicts — the
-# runtime asserts the two agree on pop counts at every flush.
-
-def device_rings(num_queues: int, capacity: int,
-                 *, packet_words: int = pkt.PACKET_WORDS) -> dict:
-    """Fresh empty device ring state pytree for ``num_queues`` rings."""
-    import jax.numpy as jnp
-    return {
-        "buf": jnp.zeros((num_queues * capacity, packet_words), jnp.uint32),
-        "head": jnp.zeros(num_queues, jnp.int32),
-        "size": jnp.zeros(num_queues, jnp.int32),
-    }
-
-
-def device_push(rings: dict, rows, qids, count, *, capacity: int) -> dict:
-    """Push a mixed-queue burst: ``rows[i]`` goes to ring ``qids[i]`` for
-    ``i < count``; per-queue arrival order is burst order; each queue
-    admits ``min(offered, free)`` and tail-drops the rest (identical to
-    ``PacketRing.push`` run per queue on the burst's subsets).
-
-    Traceable (fixed shapes); ``count`` may be a traced scalar.  Rows at
-    and beyond ``count`` are ignored via an out-of-range scatter-drop.
-    """
-    import jax.numpy as jnp
-    num_queues = rings["head"].shape[0]
-    bmax = rows.shape[0]
-    valid = jnp.arange(bmax, dtype=jnp.int32) < count
-    onehot = ((qids[:, None] == jnp.arange(num_queues)[None, :])
-              & valid[:, None])
-    # rank of row i within its queue's subset of this burst
-    rank = jnp.cumsum(onehot.astype(jnp.int32), axis=0) - 1
-    ri = jnp.take_along_axis(rank, qids[:, None], axis=1)[:, 0]
-    offered = onehot.sum(axis=0, dtype=jnp.int32)
-    free = jnp.int32(capacity) - rings["size"]
-    admit = valid & (ri < free[qids])
-    dest = (rings["head"][qids] + rings["size"][qids] + ri) % capacity
-    flat = jnp.where(admit, qids * capacity + dest,
-                     num_queues * capacity)            # OOB -> dropped
-    buf = rings["buf"].at[flat].set(rows, mode="drop")
-    size = rings["size"] + jnp.minimum(offered, jnp.maximum(free, 0))
-    return {"buf": buf, "head": rings["head"], "size": size}
-
-
-def device_pop(rings: dict, batch: int, width: int, *, capacity: int,
-               cols: tuple | None = None):
-    """Pop up to ``batch`` rows FIFO from every ring and *compact* the
-    results queue-major into one ``(width, words)`` batch (no per-queue
-    padding): row ``p`` of the output is row ``p - offset[q]`` of queue
-    ``q``'s pop, where ``q`` is the queue whose range covers ``p``.
-
-    Returns ``(rings', popped, qq, pvalid, n)`` with ``qq`` the per-row
-    queue id, ``pvalid`` the compaction validity mask and ``n`` the (Q,)
-    per-queue pop counts.  ``width`` must be static and >= the actual
-    total pops (the caller sizes it from the host mirror); ``batch`` may
-    be a traced scalar (the megastep gates padded scan steps with 0).
-    ``cols`` (static) narrows the gather to those word columns — the
-    megastep's fast path only needs the slot / control / first payload
-    words per row, so it skips moving the other 269.
-    """
-    import jax.numpy as jnp
-    num_queues = rings["head"].shape[0]
-    n = jnp.minimum(rings["size"], jnp.asarray(batch, jnp.int32))  # (Q,)
-    csum = jnp.cumsum(n)
-    off = csum - n                                          # exclusive
-    pos = jnp.arange(width, dtype=jnp.int32)
-    qq = jnp.clip(jnp.searchsorted(csum, pos, side="right"),
-                  0, num_queues - 1).astype(jnp.int32)
-    pvalid = pos < csum[-1]
-    rk = pos - off[qq]
-    idx = qq * capacity + (rings["head"][qq]
-                           + jnp.where(pvalid, rk, 0)) % capacity
-    if cols is None:
-        popped = rings["buf"][idx]
-    else:
-        popped = rings["buf"][idx[:, None],
-                              jnp.asarray(cols, jnp.int32)[None, :]]
-    head = (rings["head"] + n) % capacity
-    out = {"buf": rings["buf"], "head": head, "size": rings["size"] - n}
-    return out, popped, qq, pvalid, n

@@ -43,26 +43,27 @@ bit.  Fan-out modes (``fanout=``) only split that work:
                   as the per-queue reference the parity tests compare
                   the flat launch with.
 
-The tick loop is a 3-stage pipeline (dispatch / device / retire) with a
-bounded in-flight window, the multi-queue form of
-``switching.replay_trace(stream=True)``: each ``tick()`` pops at most
-``batch`` rows per queue, pads to the static batch shape (no
-recompiles), issues the launch asynchronously, and retires the oldest
-tick once the window is full.  By default (``pipeline_depth=None``) the
-window is read from the rings: a tick stays in flight only while some
-ring still holds a whole ``batch`` after its pop, so the next tick is
-already waiting; its result pull starts at launch and it retires behind
-the next tick's launch.  Otherwise it retires at once, so a lightly
-loaded stream waits no extra tick.  An integer ``pipeline_depth`` fixes
-the window at that many ticks; ``1`` is the synchronous loop.  Every
-window produces bit-identical verdicts because every tick captures the
-bank/RETA version current at its dispatch.
+The tick loop is the runtime's one execution engine: a 3-stage pipeline
+(dispatch / device / retire) with a bounded in-flight window, the
+multi-queue form of ``switching.replay_trace(stream=True)``: each
+``tick()`` pops at most ``batch`` rows per queue, pads to the static
+batch shape (no recompiles), issues the launch asynchronously, and
+retires the oldest tick once the window is full.  By default
+(``pipeline_depth=None``) the window is read from the rings: a tick
+stays in flight only while some ring still holds a whole ``batch`` after
+its pop, so the next tick is already waiting; its result pull starts at
+launch and it retires behind the next tick's launch.  Otherwise it
+retires at once, so a lightly loaded stream waits no extra tick.  An
+integer ``pipeline_depth`` fixes the window at that many ticks; ``1`` is
+the synchronous loop.  Every window produces bit-identical verdicts
+because every tick captures the bank/RETA version current at its
+dispatch.
 ``audit=True`` re-scores every tick through the exact ``take`` path
 *against that captured bank* and counts verdict mismatches — valid
 across every control command kind, not just slot swaps.
 
 ``self.spans`` (`repro.obs.spans.HostSpans`, off until enabled) names
-each host step of the sequential engine: ``dp.dispatch`` (``.hash``,
+each host step of the tick loop: ``dp.dispatch`` (``.hash``,
 ``.push``), ``dp.tick`` (``.control``, ``.pop``, ``.pad``, then per
 launch ``.h2d`` and ``.launch``) and the retire (``dp.retire.wait``, per
 launch ``.d2h``, per queue ``.tap``, ``.telemetry``, ``.audit``), with
@@ -226,7 +227,6 @@ class DataplaneRuntime:
         audit: bool = False,
         record: bool = False,
         pipeline_depth: int | None = None,
-        megastep_ticks: int = 1,
         policy=None,
         fault_injector=None,
         log_capacity: int | None = None,
@@ -302,30 +302,6 @@ class DataplaneRuntime:
         self.failed_queues: set[int] = set()
         self.bucket_load = np.zeros(len(self.reta), np.int64)
         self._step, self._kernel_rows = self._build_fanout(fanout)
-        if megastep_ticks < 1:
-            raise ValueError("megastep_ticks must be >= 1")
-        self.megastep_ticks = int(megastep_ticks)
-        # Deferred (megastep) mode: dispatch/tick stage work and run the
-        # authoritative host ring simulation; a window of N ticks executes
-        # on device in ONE compiled scan at flush (DESIGN.md §13).  Typed
-        # fault injection needs per-tick host control, and the megastep's
-        # batched forward replicates the fused strategy on the reference
-        # backend only — every other configuration falls back to the
-        # sequential loop.  Verdicts and telemetry totals are
-        # bit-identical either way.
-        self._mega = None
-        if (self.megastep_ticks > 1 and fault_injector is None
-                and strategy == "fused"):
-            if _ops._resolve(backend) == "ref":
-                from repro.dataplane.megastep import MegastepEngine
-                self._mega = MegastepEngine(self)
-
-    @property
-    def engine(self) -> str:
-        """The execution engine that runs the ticks: ``megastep`` (one
-        compiled scan per window) or ``sequential`` (the per-tick loop,
-        also what ``megastep_ticks > 1`` falls back to when ineligible)."""
-        return "megastep" if self._mega is not None else "sequential"
 
     # -- worker construction ------------------------------------------------
 
@@ -425,11 +401,6 @@ class DataplaneRuntime:
             self._install_reta(np.asarray(cmd.reta, np.int32))
         elif not apply_routing_command(self, cmd):
             raise TypeError(f"not a control command: {cmd!r}")
-        if self._mega is not None:
-            # deferred mode: the host mirror just mutated; serialize the
-            # same mutation into the on-device epoch queue so it applies
-            # at the matching scan step of the staged window
-            self._mega.stage_delta(cmd)
 
     def _fault_check(self, point: str) -> None:
         """Consult the armed ``FaultInjector`` (if any) at a stage/apply
@@ -447,9 +418,7 @@ class DataplaneRuntime:
                     slot_swaps=self.telemetry.slot_swaps,
                     reta_updates=self.telemetry.reta_updates,
                     bankswap=(self._bankbuf.mark()
-                              if self._bankbuf is not None else None),
-                    mega=(self._mega.delta_mark()
-                          if self._mega is not None else None))
+                              if self._bankbuf is not None else None))
 
     def _rollback_control_state(self, s: dict) -> None:
         if self._bankbuf is not None and s.get("bankswap") is not None:
@@ -464,8 +433,6 @@ class DataplaneRuntime:
         self.bucket_load = s["bucket_load"]
         self.telemetry.slot_swaps = s["slot_swaps"]
         self.telemetry.reta_updates = s["reta_updates"]
-        if self._mega is not None and s.get("mega") is not None:
-            self._mega.delta_rollback(s["mega"])
 
     def _prestage_epoch(self, rec) -> None:
         """Submit-time hook (``ControlPlane.submit``): stage the epoch's
@@ -512,19 +479,6 @@ class DataplaneRuntime:
         else:
             self.bank = self._put_bank(bank)
 
-    def bank_pin(self):
-        """Pin the current active bank buffer against donation (taken by
-        holders that outlive the next epoch, e.g. an open megastep
-        window).  Returns an opaque handle for ``bank_unpin``; None when
-        double buffering is off (nothing is ever donated then)."""
-        return (self._bankbuf.pin_active()
-                if self._bankbuf is not None else None)
-
-    def bank_unpin(self, handle) -> None:
-        """Release a ``bank_pin`` handle."""
-        if handle is not None and self._bankbuf is not None:
-            self._bankbuf.unpin(handle)
-
     def _install_reta(self, reta: np.ndarray) -> None:
         reta = np.asarray(reta, np.int32)
         if reta.min() < 0 or reta.max() >= self.num_queues:
@@ -538,24 +492,9 @@ class DataplaneRuntime:
         """Apply queued epochs at a *fully quiescent* boundary: in-flight
         ticks retire first, so the wrong-verdict counter each epoch
         snapshots has absorbed every pre-epoch tick and per-epoch
-        continuity attribution is exact even at pipeline_depth > 1.
-
-        In deferred (megastep) mode epochs do NOT force a flush — that
-        is the point of the on-device epoch queue: the epoch applies
-        eagerly to the host mirrors (exact atomic apply / rollback /
-        log) and its serialized deltas land mid-window at the matching
-        scan step.  The window only flushes early when the epoch batch
-        would overflow the bounded device queue.  Trade-off: the
-        ``wrong_verdict_at_apply`` each epoch snapshots is then the
-        value as of the last flush — identical in the zero-wrong-verdict
-        world the audit enforces, coarser only once something is already
-        broken."""
+        continuity attribution is exact whatever the in-flight window."""
         if self.control.has_pending:
-            if self._mega is not None:
-                self._mega.prepare_epochs(
-                    sum(len(r.commands) for r in self.control.pending))
-            else:
-                self.retire_all()
+            self.retire_all()
             self.control.apply_pending(self._tick_count)
 
     def _tick_boundary(self) -> None:
@@ -657,10 +596,6 @@ class DataplaneRuntime:
                 per_queue.append({"offered": int(rows.shape[0]),
                                   "admitted": admitted,
                                   "dropped": int(rows.shape[0]) - admitted})
-        if self._mega is not None:
-            # deferred mode: the host rings above stay authoritative;
-            # the device replays the identical admission at flush
-            self._mega.stage_burst(packets_np, q)
         return {"per_queue": per_queue,
                 "dropped": sum(p["dropped"] for p in per_queue)}
 
@@ -704,11 +639,6 @@ class DataplaneRuntime:
             self._tick_boundary()
         self._tick_count += 1
         self.telemetry.runtime_ticks += 1
-        if self._mega is not None:
-            # deferred mode: pop the host mirror now (authoritative FIFO
-            # order / counters), run the compute on device at flush —
-            # ``pipeline_depth`` is superseded by the scan window
-            return self._mega.stage_tick()
         with sp.span("dp.tick.pop") as pop:
             popped = [ring.pop(self.batch) for ring in self.rings]
         counts = [rows.shape[0] for rows, _ in popped]
@@ -811,11 +741,7 @@ class DataplaneRuntime:
             self.completed_slots[q].extend(int(s) for s in slots)
 
     def retire_all(self) -> None:
-        """Flush the pipeline: retire every in-flight tick (oldest first).
-        In deferred mode this is the megastep flush point — the staged
-        window runs on device and drains to telemetry/taps/recorder."""
-        if self._mega is not None:
-            self._mega.flush()
+        """Flush the pipeline: retire every in-flight tick (oldest first)."""
         while self._inflight:
             self._retire(self._inflight.popleft())
         if self.telemetry.has_sink:
@@ -824,15 +750,11 @@ class DataplaneRuntime:
             self.telemetry.emit_delta(tick=self._tick_count)
 
     def in_flight_rows(self) -> list[int]:
-        """Rows popped but not yet retired, per queue (pipelined ticks,
-        plus the staged-but-unflushed megastep window in deferred mode —
-        conservation is checkable mid-window without forcing a flush)."""
+        """Rows popped but not yet retired, per queue (the in-flight
+        window's ticks), so conservation is checkable mid-window."""
         out = [0] * self.num_queues
         for rec in self._inflight:
             for q, n in enumerate(rec.counts):
-                out[q] += n
-        if self._mega is not None:
-            for q, n in enumerate(self._mega.staged_rows()):
                 out[q] += n
         return out
 
@@ -866,7 +788,6 @@ class DataplaneRuntime:
         out["conservation"] = self.audit_conservation()
         out["fanout"] = self.fanout
         out["strategy"] = self.strategy
-        out["engine"] = self.engine
         out["backend"] = _ops._resolve(self.backend)
         out["pipeline_depth"] = self.pipeline_depth
         out["policy"] = getattr(self.policy, "name", None)

@@ -32,35 +32,6 @@ PACK = 32
 
 
 # ---------------------------------------------------------------------------
-# double-buffered bank view (zero-copy commit, DESIGN.md §14)
-#
-# The kernels themselves are already pointer-flip friendly: the slot id
-# table in SMEM is the only thing that decides which HBM bank entry the
-# DMA engine fetches.  To double-buffer at kernel level, lay both bank
-# copies out as ONE (2K, ...) allocation (``stack_double_bank``) and
-# offset the slot table by ``active * K`` (``flip_slots``) — committing a
-# swap changes one scalar, the DMA steers into the other half, and no
-# weight ever moves.  ``fused_forward`` consumes the same ``block_slots``
-# argument, so the identical two helpers serve the fused executor.
-# ---------------------------------------------------------------------------
-
-def stack_double_bank(front, back) -> jnp.ndarray:
-    """Concatenate two structurally identical (K, ...) bank leaves (or
-    pytrees) into the (2K, ...) double-buffer layout the kernels index
-    with ``flip_slots``-offset slot ids."""
-    return jax.tree_util.tree_map(
-        lambda a, b: jnp.concatenate([a, b], axis=0), front, back)
-
-
-def flip_slots(block_slots: jnp.ndarray, active, k: int) -> jnp.ndarray:
-    """Steer a per-block slot table at the ``active`` half (0 or 1) of a
-    ``stack_double_bank`` layout.  ``active`` may be a traced scalar —
-    the flip is data, not code: one compiled kernel serves both halves,
-    and a commit is a change of this one scalar."""
-    return (block_slots + jnp.int32(active) * jnp.int32(k)).astype(jnp.int32)
-
-
-# ---------------------------------------------------------------------------
 # float banked matmul: y[i] = x[i] @ W[slot_of_block(i)] (+ b)
 # ---------------------------------------------------------------------------
 

@@ -36,14 +36,6 @@ to materialize a payload view.
 The reg0 constants are mirrored from ``repro.core.packet`` (the kernels
 package stays importable without the core layer); ``repro.core.pipeline``
 asserts they agree.
-
-Double-buffered banks (DESIGN.md §14): selection is steered entirely by
-the prefetched ``block_slots`` table, so the zero-copy commit story from
-``repro.kernels.banked_matmul`` applies unchanged — lay the active and
-shadow banks out as one (2K, ...) allocation (``stack_double_bank``) and
-pass ``flip_slots(block_slots, active, k)``; a SwapSlot commit then
-changes only the ``active`` scalar, and the DMA fetches from the other
-half with zero weight movement (see ``double_buffered_forward``).
 """
 
 from __future__ import annotations
@@ -243,30 +235,3 @@ def fused_forward(
         name="fused_forward",
     )(*operands)
     return tuple(out) if with_actions else out[0]
-
-
-def double_buffered_forward(
-    x: jnp.ndarray,
-    front: dict,               # bank pytree A: w1p/b1/w2/b2 (K, ...) leaves
-    back: dict,                # bank pytree B, same structure
-    active,                    # scalar 0/1 (may be traced) — which is live
-    block_slots: jnp.ndarray,  # (n_blocks,) i32 slot ids in [0, K)
-    row_ids: jnp.ndarray | None = None,
-    **kwargs,
-):
-    """``fused_forward`` over a double-buffered bank (DESIGN.md §14).
-
-    The two bank copies are concatenated on the slot axis and the
-    per-block slot table is offset into the ``active`` half — so a
-    SwapSlot commit is the change of ONE scalar, never a weight move,
-    even at kernel level.  ``active`` may be a traced value carried in
-    scan state (the megastep's ``DeviceDelta`` path), keeping the flip
-    inside one compiled program.  Accepts every ``fused_forward``
-    keyword."""
-    from repro.kernels.banked_matmul import flip_slots, stack_double_bank
-    both = stack_double_bank(front, back)
-    k = front["b1"].shape[0]
-    return fused_forward(
-        x, both["w1p"], both["b1"], both["w2"], both["b2"],
-        flip_slots(block_slots, active, k), row_ids, **kwargs)
-

@@ -238,8 +238,7 @@ def _replay_main(args) -> dict:
           f"{trace.total_packets} packets, "
           f"{len(trace.command_timeline())} command epoch(s), "
           f"{hosts} host(s) x {queues} queue(s)")
-    rt = workloads.make_runtime(trace, audit=args.audit,
-                                megastep_ticks=args.megastep_ticks)
+    rt = workloads.make_runtime(trace, audit=args.audit)
     for shard in _shards(rt):
         shard.spans.enable()
     observer = _start_observer(rt, args,
@@ -351,11 +350,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--policy", default=None,
                     choices=["static", "least-depth", "drop-rate"],
                     help="closed-loop routing policy (default: none)")
-    ap.add_argument("--megastep-ticks", type=int, default=1,
-                    help="run N ticks on-device in one compiled scan "
-                         "(deferred megastep mode, DESIGN.md §13); 1 = "
-                         "the sequential per-tick loop.  Verdicts and "
-                         "telemetry totals are bit-identical at any N")
     ap.add_argument("--pipeline-depth", type=int, default=1,
                     help="bounded in-flight tick window (1 = synchronous)")
     ap.add_argument("--scale", type=int, default=1,
@@ -485,8 +479,7 @@ def main(argv=None) -> dict:
     recording = bool(args.trace)
     kw = dict(strategy=args.strategy, fanout=args.fanout, batch=args.batch,
               ring_capacity=args.ring_capacity, audit=args.audit,
-              pipeline_depth=args.pipeline_depth,
-              megastep_ticks=args.megastep_ticks, policy=policy,
+              pipeline_depth=args.pipeline_depth, policy=policy,
               record=recording, fault_injector=injector,
               log_capacity=args.log_capacity, log_spill=args.log_spill)
     if args.hosts > 1:
@@ -504,12 +497,7 @@ def main(argv=None) -> dict:
           f"strategy={args.strategy}, "
           f"ring={args.ring_capacity}, depth={rt.pipeline_depth}, "
           f"policy={getattr(policy, 'name', None)}")
-    print(f"engine: {rt.engine}, backend={ops._resolve('auto')} on "
-          f"{jax.default_backend()}")
-    if args.megastep_ticks > 1 and rt.engine != "megastep":
-        print(f"engine: --megastep-ticks {args.megastep_ticks} not armed "
-              f"(the megastep runs the fused strategy on the ref backend "
-              f"without a fault plan); ticks run sequentially")
+    print(f"backend={ops._resolve('auto')} on {jax.default_backend()}")
 
     stream, detector = _make_detector(rt, args, num_slots=args.slots)
     observer = _start_observer(rt, args, num_slots=args.slots,

@@ -181,8 +181,8 @@ class HostSpans:
 def tick_summary(snap: dict) -> dict | None:
     """The ``dp.tick`` spans of a ``HostSpans.snapshot()`` in
     microseconds: how many, their mean and longest, and the self time
-    per tick of each tick-loop span (``dp.tick*``, ``dp.retire.*``,
-    ``dp.flush``).  None when no tick was recorded."""
+    per tick of each tick-loop span (``dp.tick*``, ``dp.retire.*``).
+    None when no tick was recorded."""
     tick = snap["spans"].get(TICK)
     if not tick:
         return None
@@ -194,7 +194,7 @@ def tick_summary(snap: dict) -> dict | None:
         "self_us_per_tick": {
             name: s["self_ns"] / n / 1e3
             for name, s in snap["spans"].items()
-            if name.startswith((TICK, "dp.retire.", "dp.flush"))},
+            if name.startswith((TICK, "dp.retire."))},
     }
 
 

@@ -447,6 +447,87 @@ def test_deferred_counter_counts_ticks_retired_behind_a_launch(bank2, rng):
         assert sum(rt.in_flight_rows()) == 0
 
 
+def _make_bursts(seed: int, sizes: list[int]) -> list[np.ndarray]:
+    """Per-tick bursts over a tiny payload pool: repeated payloads with
+    per-packet word-0 twists mixed with a few fully unique payloads, and
+    a per-packet sequence stamp."""
+    rng = np.random.default_rng(seed)
+    pool = rng.integers(0, 2**32, (3, pkt.PAYLOAD_WORDS), dtype=np.uint32)
+    seq = 0
+    bursts = []
+    for n in sizes:
+        if n == 0:
+            bursts.append(np.zeros((0, pkt.PACKET_WORDS), np.uint32))
+            continue
+        payload = pool[rng.integers(0, pool.shape[0], n)].copy()
+        payload[:, 0] ^= rng.integers(0, 2**32, n, dtype=np.uint32)
+        unique = rng.random(n) < 0.2
+        payload[unique] = rng.integers(
+            0, 2**32, (int(unique.sum()), pkt.PAYLOAD_WORDS), dtype=np.uint32)
+        rows = pkt.make_packets(rng.integers(0, 2, n).astype(np.int32),
+                                payload)
+        rows[:, pkt.CONTROL_WORD_LO] = rng.integers(0, 2, n).astype(np.uint32)
+        rows[:, SEQ_WORD] = np.arange(seq, seq + n, dtype=np.uint32)
+        seq += n
+        bursts.append(rows)
+    return bursts
+
+
+def _observed(rt) -> tuple:
+    """Everything the window must not change, as one comparable value:
+    per-queue completion streams, counting telemetry, epoch apply ticks.
+    (Wall-clock fields — latency — are excluded.)"""
+    queues = []
+    for q, qs in enumerate(rt.snapshot()["queues"]):
+        queues.append((
+            tuple(rt.completed_seq[q]),
+            tuple(rt.completed_verdicts[q]),
+            tuple(rt.completed_slots[q]),
+            qs["completed"], qs["dropped"],
+            tuple(qs["per_slot_total"]), tuple(qs["per_slot_malicious"]),
+            tuple(sorted(qs["actions"].items())),
+        ))
+    epochs = tuple((r.applied_tick, type(r.commands[0]).__name__)
+                   for r in rt.control.log if r.applied)
+    return (tuple(queues), epochs, rt.telemetry.slot_swaps,
+            rt.telemetry.reta_updates)
+
+
+@pytest.mark.parametrize("depth", [None, 2, 3])
+@settings(max_examples=20, deadline=None)
+@given(
+    seed=st.integers(0, 2**16),
+    sizes=st.lists(st.integers(0, 24), min_size=3, max_size=10),
+    swap_at=st.integers(0, 9),
+    reta_at=st.integers(0, 9),
+)
+def test_pipeline_window_equals_synchronous(bank2, depth, seed, sizes,
+                                            swap_at, reta_at):
+    """Any in-flight window serves what ``pipeline_depth=1`` serves, bit
+    for bit, over random burst sizes with a SwapSlot and a ProgramReta
+    epoch landing at random ticks (both runs audited)."""
+    bursts = _make_bursts(seed, sizes)
+    epochs = {swap_at: [SwapSlot(
+        swap_at % 2, executor.init_params(jax.random.PRNGKey(seed)))]}
+    epochs.setdefault(reta_at, []).append(ProgramReta(
+        tuple(int(x) for x in (np.arange(16) + reta_at) % 2)))
+    runs = []
+    for d in (1, depth):
+        rt = make_rt(bank2, num_queues=2, strategy="fused", batch=8,
+                     ring_capacity=256, audit=True, record=True,
+                     pipeline_depth=d)
+        for t, burst in enumerate(bursts):
+            for cmd in epochs.get(t, ()):
+                rt.control.submit(cmd)
+            rt.dispatch(burst)
+            rt.tick()
+        rt.drain()
+        assert rt.telemetry.wrong_verdict == 0
+        assert rt.audit_conservation()["ok"]
+        runs.append(_observed(rt))
+    assert runs[0] == runs[1]
+
+
 # ---------------------------------------------------------------------------
 # continuity: zero wrong verdicts across EVERY command kind
 # ---------------------------------------------------------------------------

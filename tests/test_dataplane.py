@@ -316,6 +316,23 @@ def test_telemetry_snapshot(bank2):
         assert acts["forward"] + acts["drop"] + acts["flag"] == q["completed"]
 
 
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+def test_snapshot_reports_resolved_backend(bank2, rng, backend):
+    """The run report names the backend the ticks ran on, and no
+    execution-engine key: the tick loop is the only engine."""
+    rt = DataplaneRuntime(bank2, num_queues=2, strategy="fused", batch=8,
+                          backend=backend)
+    rows = pkt.make_packets(
+        rng.integers(0, 2, 16),
+        rng.integers(0, 2**32, (16, pkt.PAYLOAD_WORDS), dtype=np.uint32))
+    rt.dispatch(rows, queues=np.arange(16) % 2)
+    rt.drain()
+    snap = rt.snapshot()
+    assert snap["backend"] == backend
+    assert "engine" not in snap
+    assert snap["completed_total"] == 16 and snap["conservation"]["ok"]
+
+
 # ---------------------------------------------------------------------------
 # continuity: online slot swap under multi-queue churn
 # ---------------------------------------------------------------------------

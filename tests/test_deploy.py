@@ -17,7 +17,7 @@ from repro.core import bank as bank_lib
 from repro.core import executor
 from repro.core import packet as pkt
 from repro.dataplane import DataplaneRuntime, MeshDataplane, workloads
-from repro.obs import AnomalyDetector, TelemetryStream
+from repro.obs import AnomalyDetector, TelemetryStream, attach
 from repro.obs import spans
 
 
@@ -173,6 +173,52 @@ def test_sampler_window_filters_by_tick(bank2, corpus):
     w2, l2, _v2, _s2 = sampler.window_since(cut)
     assert 0 < l2.size < _l.size
     assert (oracle.lookup(w2) == l2).all()
+
+
+@pytest.mark.parametrize("depth", [None, 4])
+def test_deferred_retires_respect_sampler_and_stream_bounds(bank2, corpus,
+                                                            depth):
+    """Ticks retired behind a later launch reach the deploy/obs taps back
+    to back (the derived window hands them ticks in pairs, a fixed
+    window of 4 and the final drain in runs): ``PacketSampler.max_pending``
+    must still bound the labeling backlog, and ``TelemetryStream``
+    overflow accounting must stay conserved
+    (``next_sid == buffered + dropped_events``)."""
+    pool, _labels, oracle = corpus
+    rt = DataplaneRuntime(bank2, num_queues=2, strategy="fused", batch=16,
+                          ring_capacity=1024, pipeline_depth=depth)
+    rt.spans.enable()
+    max_pending = 3
+    sampler = deploy.PacketSampler(oracle, num_slots=2, per_tick=8,
+                                   max_pending=max_pending).attach(rt)
+    stream = TelemetryStream(capacity=4)  # tiny: force real overflow
+    attach(rt, stream)
+    flush_sizes = []
+    orig_flush = sampler.flush
+    sampler.flush = lambda: (flush_sizes.append(len(sampler._pending)),
+                             orig_flush())[-1]
+    rng = np.random.default_rng(0)
+    peak = 0
+    for _ in range(40):
+        idx = rng.integers(0, pool.shape[0], 48)
+        rt.dispatch(pkt.make_packets(rng.integers(0, 2, 48), pool[idx]))
+        rt.tick()
+        peak = max(peak, len(sampler._pending))
+    rt.drain()
+    peak = max(peak, len(sampler._pending))
+    sampler.detach()  # final flush
+    assert rt.spans.counters.get("dp.retire.deferred", 0) > 0
+    completed = rt.snapshot()["completed_total"]
+    assert completed > 0
+    assert sampler.seen == completed
+    # the backlog bound held across every back-to-back retire
+    assert peak <= max_pending
+    assert max(flush_sizes, default=0) <= max_pending
+    assert sampler.labeled + sampler.unknown == sampler.sampled
+    # stream conservation: every event is either retained or counted out
+    s = stream.snapshot_stats()
+    assert s["next_sid"] == s["buffered"] + s["dropped_events"]
+    assert s["dropped_events"] > 0  # the tiny ring really overflowed
 
 
 def test_double_attach_rejected(bank2):

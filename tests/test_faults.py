@@ -308,6 +308,37 @@ def test_single_host_stall_and_stage_error_nonfatal(bank2):
     assert rt.audit_conservation()["ok"]
 
 
+def test_stall_under_derived_window_completes_the_same_seqs(bank2):
+    """An injected ``StallHost`` while the derived window
+    (``pipeline_depth=None``) holds a tick in flight: the audits pass,
+    and once drained every queue has completed the same seqs, in the
+    same order, as the unstalled run."""
+    trace = render(small_phases(2, ticks=8, burst=48), num_slots=2, seed=7,
+                   num_queues=2)
+    runs = {}
+    for name, plan in (("plain", None), ("stalled", faults.FaultPlan(
+            faults=(faults.StallHost(0, 2, 2),), name="stall"))):
+        rt = DataplaneRuntime(
+            bank2, num_queues=2, strategy="fused", batch=8,
+            ring_capacity=256, audit=True, record=True,
+            fault_injector=plan and faults.FaultInjector(plan))
+        assert rt.pipeline_depth is None
+        rt.spans.enable()
+        for t, b in enumerate(trace.bursts[0]):
+            rt.dispatch(b)
+            rt.tick()
+            if plan is not None and t == 2:
+                # the stalled tick served nothing and retired nothing
+                assert sum(rt.in_flight_rows()) > 0
+        rt.drain()
+        assert rt.spans.counters.get("dp.retire.deferred", 0) > 0
+        aud = rt.audit_conservation()
+        assert aud["ok"] and aud["wrong_verdict"] == 0
+        assert aud["totals"]["completed"] == trace.total_packets
+        runs[name] = rt.completed_seq
+    assert runs["stalled"] == runs["plain"]
+
+
 # ---------------------------------------------------------------------------
 # API surface
 # ---------------------------------------------------------------------------

@@ -1,8 +1,8 @@
 """Zero-copy model switching (DESIGN.md §14): DoubleBufferedBank
-staging/flip/rollback semantics, the kernel-level (2K,...) double-bank
-view, SlotCache LRU/pinning/prefetch, and the property that any
-swap/traffic interleaving under the cache yields verdicts bit-identical
-to the re-staging commit path with zero wrong-verdict packets."""
+staging/flip/rollback semantics, SlotCache LRU/pinning/prefetch, and the
+property that any swap/traffic interleaving under the cache yields
+verdicts bit-identical to the re-staging commit path with zero
+wrong-verdict packets."""
 
 import jax
 import numpy as np
@@ -12,8 +12,6 @@ from _hyp import given, settings, st
 from repro.control import CacheError, SlotCache, SlotMixPrefetcher, SwapSlot
 from repro.core import bank as bank_lib, executor, packet as pkt
 from repro.dataplane import DataplaneRuntime
-from repro.kernels.banked_matmul import (banked_matmul, flip_slots,
-                                         stack_double_bank)
 
 
 @pytest.fixture(scope="module")
@@ -94,8 +92,7 @@ def test_mark_restore_rolls_back_a_flip(bank4, params_pool):
 
 def test_pin_forces_copy_on_write(bank4, params_pool):
     """A pinned buffer that becomes the staging shadow after a flip must
-    be un-aliased, not mutated — its holder (the megastep window) may
-    still read it."""
+    be un-aliased, not mutated — its holder may still read it."""
     dbb = bank_lib.DoubleBufferedBank(bank4)
     handle = dbb.pin_active()
     snapshot = host_copy(handle.tree)
@@ -119,47 +116,6 @@ def test_runtime_flip_equals_restage(bank4, params_pool):
     assert banks_equal(banks[True], banks[False])
     assert banks_equal(banks[True],
                        bank_lib.update_slot(bank4, 1, params_pool[0]))
-
-
-# ---------------------------------------------------------------------------
-# kernel-level (2K, ...) double-bank view
-# ---------------------------------------------------------------------------
-
-def test_stack_double_bank_flip_selects_halves():
-    key = jax.random.PRNGKey(3)
-    k, d, h, bsz, bb = 3, 16, 8, 64, 16
-    kf, kb, kx = jax.random.split(key, 3)
-    wf = jax.random.normal(kf, (k, d, h), np.float32)
-    bf = jax.random.normal(kf, (k, h), np.float32)
-    wb = jax.random.normal(kb, (k, d, h), np.float32)
-    bb_ = jax.random.normal(kb, (k, h), np.float32)
-    x = jax.random.normal(kx, (bsz, d), np.float32)
-    slots = np.asarray([0, 2, 1, 0], np.int32)
-    both_w = stack_double_bank(wf, wb)
-    both_b = stack_double_bank(bf, bb_)
-    assert both_w.shape == (2 * k, d, h)
-    for active, (w, b) in enumerate(((wf, bf), (wb, bb_))):
-        want = banked_matmul(x, w, b, slots, block_b=bb, interpret=True)
-        got = banked_matmul(x, both_w, both_b,
-                            flip_slots(slots, active, k),
-                            block_b=bb, interpret=True)
-        np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
-
-
-def test_double_buffered_forward_equivalence(bank4):
-    from repro.kernels.fused_forward import (double_buffered_forward,
-                                             fused_forward)
-    back = executor.init_bank(jax.random.PRNGKey(9), 4)
-    rng = np.random.default_rng(5)
-    w_words = bank4["w1p"].shape[-1]
-    x = rng.integers(0, 2**32, (64, w_words), dtype=np.uint32)
-    slots = np.asarray([1, 3], np.int32)
-    for active, src in ((0, bank4), (1, back)):
-        want = fused_forward(x, src["w1p"], src["b1"], src["w2"],
-                             src["b2"], slots, block_b=32, interpret=True)
-        got = double_buffered_forward(x, bank4, back, active, slots,
-                                      block_b=32, interpret=True)
-        np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
 # ---------------------------------------------------------------------------
