@@ -9,9 +9,11 @@ construction:
 
 * the flow tuple lives in reg0 spare words 4..7 (16 B — src/dst address,
   ports, protocol as the traffic engine lays them out);
-* ``toeplitz_hash`` runs the standard MSB-first sliding-window XOR over a
-  secret key (default: the Microsoft reference RSS key), vectorized over
-  the batch;
+* ``toeplitz_hash`` computes the standard MSB-first sliding-window XOR
+  over a secret key (default: the Microsoft reference RSS key).  The hash
+  is linear over GF(2), so it is the XOR of one precomputed 32-bit value
+  per input byte: a burst costs one gather per byte position from
+  per-key byte tables and an XOR across them;
 * the hash indexes a 128-entry indirection table (RETA) mapping hash LSBs
   to queue ids.  Link failover is a RETA rewrite (``failover_table``), not
   a rehash — exactly how real NIC drivers migrate traffic off a dead queue.
@@ -46,12 +48,12 @@ DEFAULT_KEY = bytes(
 )
 
 
-@functools.lru_cache(maxsize=8)
 def _key_windows(key: bytes, n_bits: int) -> np.ndarray:
     """windows[j] = the 32-bit slice of ``key`` starting at bit j (MSB-first).
 
-    Toeplitz is "XOR together the key windows at every set input bit"; the
-    window table turns the per-bit shift loop into one vectorized select.
+    Toeplitz is "XOR together the key windows at every set input bit";
+    these windows are the one definition of the key that ``_byte_tables``
+    folds into per-byte lookup tables.
     """
     total_bits = len(key) * 8
     if total_bits < n_bits + 32:
@@ -64,24 +66,37 @@ def _key_windows(key: bytes, n_bits: int) -> np.ndarray:
     return out
 
 
+@functools.lru_cache(maxsize=8)
+def _byte_tables(key: bytes, n_bytes: int) -> np.ndarray:
+    """tables[i, v] = XOR of the key windows ``8*i + b`` for every set bit
+    ``b`` of byte value ``v`` (bits read MSB first): the hash of input
+    byte ``i`` alone.  (n_bytes, 256) uint32, 16 KiB for a 16-byte tuple.
+    """
+    windows = _key_windows(key, n_bytes * 8).reshape(n_bytes, 1, 8)
+    bits = np.unpackbits(np.arange(256, dtype=np.uint8)[:, None], axis=1)
+    return np.bitwise_xor.reduce(
+        np.where(bits.astype(bool), windows, np.uint32(0)), axis=-1)
+
+
 def toeplitz_hash(flow_words: np.ndarray, key: bytes = DEFAULT_KEY) -> np.ndarray:
     """Vectorized Toeplitz hash: (B, F) uint32 flow words -> (B,) uint32.
 
     Bit order matches the canonical definition: words are consumed
     big-endian, MSB first, so the result is reproducible against any
-    reference implementation fed the same 16 input bytes.
+    reference implementation fed the same 16 input bytes.  Each input
+    byte gathers its value's entry from its own table; the gathers XOR
+    together into the hash.
     """
-    fw = np.ascontiguousarray(np.asarray(flow_words, np.uint32))
+    fw = np.asarray(flow_words, np.uint32)
     if fw.ndim == 1:
         fw = fw[None, :]
-    n_bits = fw.shape[-1] * 32
-    windows = _key_windows(key, n_bits)
+    n_bytes = fw.shape[-1] * 4
+    tables = _byte_tables(key, n_bytes)
     # explicit width: reshape(-1) is ambiguous for empty batches
-    as_bytes = fw.astype(">u4").view(np.uint8).reshape(
-        *fw.shape[:-1], fw.shape[-1] * 4)
-    bits = np.unpackbits(as_bytes, axis=-1).astype(bool)  # (B, n_bits)
-    return np.bitwise_xor.reduce(
-        np.where(bits, windows, np.uint32(0)), axis=-1)
+    as_bytes = fw.astype(">u4", order="C").view(np.uint8).reshape(
+        *fw.shape[:-1], n_bytes)
+    flat = as_bytes + np.arange(0, 256 * n_bytes, 256, dtype=np.intp)
+    return np.bitwise_xor.reduce(tables.ravel().take(flat), axis=-1)
 
 
 def flow_words_of(packets: np.ndarray) -> np.ndarray:

@@ -567,6 +567,7 @@ class DataplaneRuntime:
         packets_np = np.asarray(packets_np)
         if queues is None:
             with sp.span("dp.dispatch.hash"):
+                sp.count("dp.rows_hashed", len(packets_np))
                 h = rss.toeplitz_hash(rss.flow_words_of(packets_np),
                                       self.rss_key)
                 bucket = rss.bucket_index(h, len(self.reta)).astype(np.int64)

@@ -51,11 +51,31 @@ def _toeplitz_naive(words, key=rss.DEFAULT_KEY):
     return out
 
 
-def test_toeplitz_matches_reference(rng):
-    fw = rng.integers(0, 2**32, (32, rss.FLOW_WORDS), dtype=np.uint32)
-    h = rss.toeplitz_hash(fw)
-    for i in range(fw.shape[0]):
-        assert int(h[i]) == _toeplitz_naive(fw[i])
+_RANDOM_KEY = np.random.default_rng(40).bytes(40)
+
+
+@pytest.mark.parametrize("key", [rss.DEFAULT_KEY, _RANDOM_KEY],
+                         ids=["default_key", "random_key"])
+@pytest.mark.parametrize("width", [1, 2, 3, 4])
+@pytest.mark.parametrize("batch", [0, 1, 33, 512, "1d"])
+def test_toeplitz_matches_reference(batch, width, key):
+    rng = np.random.default_rng(width)
+    shape = (width,) if batch == "1d" else (batch, width)
+    fw = rng.integers(0, 2**32, shape, dtype=np.uint32)
+    h = rss.toeplitz_hash(fw, key)
+    rows = fw.reshape(-1, width)
+    assert h.dtype == np.uint32 and h.shape == (rows.shape[0],)
+    assert [int(x) for x in h] == [_toeplitz_naive(r, key) for r in rows]
+
+
+@pytest.mark.parametrize("width", [1, 4])
+def test_toeplitz_key_length(width):
+    fw = np.arange(3 * width, dtype=np.uint32).reshape(3, width) * 0x9E3779B9
+    exact = _RANDOM_KEY[: 4 * width + 4]       # 32 bits past the input
+    assert [int(x) for x in rss.toeplitz_hash(fw, exact)] == [
+        _toeplitz_naive(r, exact) for r in fw]
+    with pytest.raises(ValueError, match="key too short"):
+        rss.toeplitz_hash(fw, exact[:-1])
 
 
 def test_rss_deterministic_and_flow_affine(rng):
@@ -284,6 +304,26 @@ def test_runtime_failover_drains_dead_queue(bank2):
     rt2.fail_queues((0,))
     assert not (rt2.reta == 0).any()
     assert set(rt2.reta) <= {1, 2, 3}
+
+
+def test_rows_hashed_counter(bank2):
+    rt = DataplaneRuntime(bank2, num_queues=4, batch=32, ring_capacity=256)
+    rt.spans.enable()
+    rng = np.random.default_rng(3)
+    bursts = [pkt.make_packets(
+        rng.integers(0, 2, n),
+        rng.integers(0, 2**32, (n, pkt.PAYLOAD_WORDS), dtype=np.uint32))
+        for n in (7, 128, 0, 300)]                 # the last one overflows
+    for b in bursts:
+        rt.dispatch(b)
+    counters = rt.spans.snapshot()["counters"]
+    assert counters["dp.rows_hashed"] == sum(len(b) for b in bursts)
+    # precomputed queue ids bypass the hash, so count nothing
+    rt.dispatch(bursts[1], queues=np.zeros(len(bursts[1]), np.int64))
+    assert rt.spans.snapshot()["counters"]["dp.rows_hashed"] == 435
+    rt.spans.disable()
+    rt.dispatch(bursts[0])
+    assert rt.spans.snapshot()["counters"]["dp.rows_hashed"] == 435
 
 
 def test_telemetry_snapshot(bank2):
